@@ -12,6 +12,8 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 PAPER = {
     # Table I: (mha_bytes, actual_bytes, ratio)
     "table1": {"deepseek-v3": (65536, 1152, 57), "llama-3-70b": (32768, 4096, 8),
@@ -920,6 +922,7 @@ def main() -> None:
                          "REPRO_KERNEL_BACKEND, else pallas on TPU / "
                          "xla elsewhere")
     args = ap.parse_args()
+    enable_compile_cache()
     t0 = time.time()
     sel = args.table
     hit_rates: Dict[str, float] = {}

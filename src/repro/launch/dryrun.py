@@ -10,20 +10,17 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3.2-1b \
         --shape decode_32k [--multi-pod] [--all] [--out results.json]
 """
+import argparse
+import json
 import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
+import re
+import sys
+import time
+from typing import Any, Dict, Optional, Tuple
 
-import argparse          # noqa: E402
-import json              # noqa: E402
-import re                # noqa: E402
-import sys               # noqa: E402
-import time              # noqa: E402
-from typing import Any, Dict, Optional, Tuple   # noqa: E402
-
-import jax               # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config import (KIND_DECODE, KIND_PREFILL, KIND_TRAIN, SHAPES,
                           ModelConfig, ShapeConfig, shape_applicable)
@@ -293,6 +290,11 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main(argv=None) -> int:
+    # 512 virtual CPU devices for the production meshes; set before the
+    # first device query initializes the backend
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count"
+                               "=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

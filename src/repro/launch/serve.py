@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.config import reduce_config
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import EngineConfig, SamplingParams, ServingEngine
 from repro.serving.cluster import (ROUTERS, ReplicaCluster)  # noqa: F401
 #                                  (ReplicaCluster re-exported here for
@@ -113,6 +114,7 @@ def main(argv=None) -> int:
                     choices=["shed", "queue"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.frontend:
         return _serve_frontend(args)

@@ -68,6 +68,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
+
 from repro.core.cache_manager import ManagerStats
 from repro.core.tiers import ConsistentHashRing, FleetKVStore
 from repro.serving.engine import EngineConfig, ServingEngine
@@ -238,15 +240,19 @@ def make_router(policy: str, **kw) -> RoutingPolicy:
 class ReplicaCluster:
     """N share-nothing engine replicas + pluggable request routing.
 
-    ``engine_factory`` builds one replica engine; the default constructs
-    ``ServingEngine(cfg, engine_cfg)`` (params re-init deterministically
-    — replicas share nothing).  The trace replay passes a factory that
-    applies its replay tier specs and virtual-clock engine config.
+    Replica i is placed on ``jax.devices()[i % n_devices]``: one chip
+    per replica, all in this one process.  ``engine_factory(device)``
+    builds one replica engine on that device; the default constructs
+    ``ServingEngine(cfg, engine_cfg, device=device)`` (params re-init
+    deterministically — replicas share nothing).  The trace replay
+    passes a factory that applies its replay tier specs and
+    virtual-clock engine config.
     """
 
     def __init__(self, cfg=None, engine_cfg: Optional[EngineConfig] = None,
                  n_replicas: int = 2, *, routing: str = "affine",
-                 engine_factory: Optional[Callable[[], ServingEngine]] = None,
+                 engine_factory: Optional[
+                     Callable[[jax.Device], ServingEngine]] = None,
                  router: Optional[RoutingPolicy] = None,
                  name_prefix: str = "replica",
                  shared_tier: bool = False,
@@ -255,7 +261,8 @@ class ReplicaCluster:
         if engine_factory is None:
             if cfg is None:
                 raise ValueError("need cfg+engine_cfg or engine_factory")
-            engine_factory = lambda: ServingEngine(cfg, engine_cfg)  # noqa: E731
+            engine_factory = lambda device: ServingEngine(  # noqa: E731
+                cfg, engine_cfg, device=device)
         self._factory = engine_factory
         self._prefix = name_prefix
         self._next_replica = 0
@@ -300,14 +307,16 @@ class ReplicaCluster:
         blocks (payloads included) pushed from their previous replica
         BEFORE the joiner takes traffic, so the first post-join turn
         hits hot instead of paying a re-prefill TTFT spike."""
+        index = self._next_replica
         if name is None:
-            name = f"{self._prefix}{self._next_replica}"
+            name = f"{self._prefix}{index}"
         self._next_replica += 1
         if name in self.engines or name in self.failed_stats:
             # a failed replica's name stays reserved: reusing it would
             # collide the stats rollups and mark the newcomer failed
             raise ValueError(f"replica {name!r} already exists")
-        eng = self._factory()
+        devices = jax.devices()
+        eng = self._factory(devices[index % len(devices)])
         if self._shared_tier:
             if self.fleet_store is None:
                 spec = next((t.spec for t in eng.manager.hierarchy.tiers

@@ -40,6 +40,7 @@ original monolithic prefill-at-admission for A/B comparison.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from dataclasses import dataclass
@@ -146,18 +147,28 @@ def _fused_dense_step(model, params, state, tokens, rng, temperature,
 
 
 class ServingEngine:
+    """``device`` pins the replica to one accelerator: its parameters
+    and KV pool are committed there, and every step runs with it as the
+    default device, so the jitted steps execute on it (``None``: JAX's
+    default device)."""
+
     def __init__(self, cfg: ModelConfig,
                  engine_cfg: Optional[EngineConfig] = None,
-                 params=None, rng: Optional[jax.Array] = None):
+                 params=None, rng: Optional[jax.Array] = None,
+                 device: Optional[jax.Device] = None):
         # a fresh EngineConfig per engine: a shared default instance
         # would leak config mutations across engines
         engine_cfg = EngineConfig() if engine_cfg is None else engine_cfg
         self.cfg = cfg
         self.ecfg = engine_cfg
+        self.device = device
         self.model = build_model(cfg)
         rng = jax.random.PRNGKey(engine_cfg.seed) if rng is None else rng
-        self.params = params if params is not None else \
-            self.model.init_params(rng)
+        with self._on_device():
+            params = params if params is not None else \
+                self.model.init_params(rng)
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.scheduler = Scheduler(cfg, SchedulerConfig(
             kv_budget_bytes=engine_cfg.kv_budget_bytes,
             max_len=engine_cfg.max_len,
@@ -178,7 +189,8 @@ class ServingEngine:
                     f"manager block size {bt}")
             self.kv = PagedKVCache(self.model, self.scheduler.n_slots,
                                    engine_cfg.max_len,
-                                   page_tokens=engine_cfg.page_tokens)
+                                   page_tokens=engine_cfg.page_tokens,
+                                   device=device)
             self._decode = jax.jit(
                 functools.partial(self.model.decode_step_paged,
                                   backend=self.kernel_backend),
@@ -189,7 +201,7 @@ class ServingEngine:
                 donate_argnums=(1,))
         else:
             self.kv = SlotKVCache(self.model, self.scheduler.n_slots,
-                                  engine_cfg.max_len)
+                                  engine_cfg.max_len, device=device)
             self._decode = jax.jit(self.model.decode_step,
                                    donate_argnums=(1,))
             self._fused_decode = jax.jit(
@@ -261,6 +273,12 @@ class ServingEngine:
         self.segment_chunks = 0        # position-explicit kernel chunks
         self.last_step_prefill_tokens = 0
         self.max_step_prefill_tokens = 0   # budget-compliance witness
+
+    def _on_device(self):
+        """Context in which arrays this engine creates land on its
+        device (a no-op for an engine on the default device)."""
+        return (contextlib.nullcontext() if self.device is None
+                else jax.default_device(self.device))
 
     # ------------------------------------------------------------------
     def bind_fleet_store(self, store, owner: str) -> bool:
@@ -828,6 +846,10 @@ class ServingEngine:
         """One engine iteration (poll transfers -> admit -> budget-select
         -> prefill chunks -> batched decode -> sample/finish); returns
         #tokens generated."""
+        with self._on_device():
+            return self._step()
+
+    def _step(self) -> int:
         sch = self.scheduler
         # completion events (scheduler polls; engine interprets)
         self._poll_transfers()
@@ -939,10 +961,7 @@ class ServingEngine:
         if self._prefill_chunk_seg is not None:
             closures.append(("prefill_chunk_seg", self._prefill_chunk_seg))
         for name, fn in closures:
-            try:
-                out[name] = int(fn._cache_size())
-            except Exception:          # jax-version-dependent private API
-                out[name] = -1
+            out[name] = int(fn._cache_size())
         return out
 
     def stats(self) -> dict:

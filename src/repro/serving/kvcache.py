@@ -60,6 +60,14 @@ def _scatter_pool(arr, pids, offs, data):
     return arr.at[:, pids, offs].set(data.astype(arr.dtype))
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _copy_pool_page(arr, dst, src):
+    """Copy page ``src`` of pool ``arr`` [L, N, page, ...] onto page
+    ``dst``, in place (donated: an eager ``.at[].set`` would hold a
+    second copy of the whole pool while it runs)."""
+    return arr.at[:, dst].set(arr[:, src])
+
+
 class _KVCacheBase:
     """Slot bookkeeping + payload conversion shared by both layouts.
 
@@ -133,12 +141,15 @@ class _KVCacheBase:
 class SlotKVCache(_KVCacheBase):
     """Fixed decode slots over the model's contiguous DecodeState."""
 
-    def __init__(self, model: Model, n_slots: int, max_len: int):
+    def __init__(self, model: Model, n_slots: int, max_len: int, *,
+                 device: Optional[jax.Device] = None):
         self.model = model
         self.cfg = model.cfg
         self.n_slots = n_slots
         self.max_len = max_len
         self.state = model.init_decode_state(n_slots, max_len)
+        if device is not None:
+            self.state = jax.device_put(self.state, device)
         self.slots = [SlotInfo() for _ in range(n_slots)]
 
     # ------------------------------------------------------------------
@@ -202,12 +213,13 @@ class PagedKVCache(_KVCacheBase):
     unmapped); `decode_state()` snapshots them (plus per-slot lengths)
     into device arrays for `Model.decode_step_paged`, which scatters the
     new token's KV into the pool and attends through the Pallas paged
-    kernels.
+    kernels.  ``device`` commits the pools to one accelerator (``None``:
+    JAX's default device).
     """
 
     def __init__(self, model: Model, n_slots: int, max_len: int, *,
                  page_tokens: int = 64, reserve_pages: Optional[int] = None,
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, device: Optional[jax.Device] = None):
         cfg = model.cfg
         self.model = model
         self.cfg = cfg
@@ -225,12 +237,12 @@ class PagedKVCache(_KVCacheBase):
         if self.mla:
             d = cfg.d_latent + cfg.d_rope
             self.pools = {"latent_pages": jnp.zeros(
-                (L, self.n_pages, self.page, d), dtype)}
+                (L, self.n_pages, self.page, d), dtype, device=device)}
         else:
             hkv = max(cfg.n_kv_heads, 1)
             shape = (L, self.n_pages, self.page, hkv, cfg.hd)
-            self.pools = {"k_pages": jnp.zeros(shape, dtype),
-                          "v_pages": jnp.zeros(shape, dtype)}
+            self.pools = {"k_pages": jnp.zeros(shape, dtype, device=device),
+                          "v_pages": jnp.zeros(shape, dtype, device=device)}
         self.tables = np.zeros((n_slots, self.pages_per_slot), np.int32)
         self._mapped = [0] * n_slots           # contiguous mapped page count
         self.slots = [SlotInfo() for _ in range(n_slots)]
@@ -299,7 +311,7 @@ class PagedKVCache(_KVCacheBase):
             return
         new = self._alloc(1)[0]
         for key, arr in self.pools.items():
-            self.pools[key] = arr.at[:, new].set(arr[:, pid])
+            self.pools[key] = _copy_pool_page(arr, new, pid)
         self.tables[slot, page_index] = new
         self.allocator.deref(pid)
         self.allocator.note_cow_copy()

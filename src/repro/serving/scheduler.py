@@ -80,7 +80,20 @@ class Scheduler:
             free_slots -= 1
         return out
 
+    def _unqueue(self, req: Request) -> None:
+        """One request, one queue: a request entering ``running`` leaves
+        the admission queues.  A no-op after ``admissible``, which popped
+        it already; a caller that admits directly would otherwise leave
+        it queued, and a later ``preempt`` would have it handed out
+        twice."""
+        for q in (self.waiting, self.preempted):
+            for i, r in enumerate(q):
+                if r is req:
+                    del q[i]
+                    return
+
     def start(self, req: Request, slot: int) -> None:
+        self._unqueue(req)
         req.phase = Phase.DECODE
         req.phase_start = time.monotonic()
         req.slot = slot
@@ -90,6 +103,7 @@ class Scheduler:
         """Admit into the chunked-prefill phase: the request holds a
         slot and consumes budget via ``plan_step`` until its chunk
         cursor reaches the prompt end."""
+        self._unqueue(req)
         req.phase = Phase.PREFILL
         req.phase_start = time.monotonic()
         req.slot = slot

@@ -388,7 +388,7 @@ def _turn_spec(turn: Turn, bt: int, vocab: int, max_new_cap: int,
 
 
 def build_engine(rcfg: ServingReplayConfig, cfg: Optional[ModelConfig] = None,
-                 max_len: int = 768) -> ServingEngine:
+                 max_len: int = 768, device=None) -> ServingEngine:
     cfg = replay_model_config(rcfg.block_tokens) if cfg is None else cfg
     hot, t1 = ENGINE_REPLAY_BLOCKS.get(rcfg.workload, (64, 96))
     hot = rcfg.hot_blocks if rcfg.hot_blocks is not None else hot
@@ -424,7 +424,7 @@ def build_engine(rcfg: ServingReplayConfig, cfg: Optional[ModelConfig] = None,
                                       seed=rcfg.fault_seed)
                         if rcfg.fault_profiles else None),
         transfer_timeout_s=rcfg.transfer_timeout_s)
-    return ServingEngine(cfg, ecfg)
+    return ServingEngine(cfg, ecfg, device=device)
 
 
 # ---------------------------------------------------------------------------
@@ -555,7 +555,9 @@ def _run_replay_core(rcfg: ServingReplayConfig, *, n_replicas: int = 1,
     router = make_router(routing, salt=ring_salt) \
         if routing == "affine" else make_router(routing)
     cluster = ReplicaCluster(
-        engine_factory=lambda: build_engine(rcfg, cfg, max_len=max_len),
+        engine_factory=lambda device: build_engine(rcfg, cfg,
+                                                   max_len=max_len,
+                                                   device=device),
         n_replicas=n_replicas, router=router, shared_tier=shared_tier)
     stall = _FetchStallModel(rcfg,
                              next(iter(cluster.engines.values())))

@@ -362,3 +362,30 @@ def test_add_replica_warmup_removes_postjoin_ttft_spike():
     assert r.warmed_sessions > 0 and r.warmed_blocks > 0
     assert r.postjoin_ttft_p95 > 0                 # the joiner served turns
     assert r.postjoin_ttft_p95 <= 1.2 * r.steady_ttft_p95
+
+
+# ---------------------------------------------------------------------------
+# device placement: one replica per device, in one process
+# ---------------------------------------------------------------------------
+def test_replicas_commit_their_arrays_to_their_own_device():
+    """Replica i holds its parameters and KV pool on
+    ``jax.devices()[i % n]`` before and after serving (committed, so its
+    jitted steps run there), and serves on it."""
+    import jax
+    c = _cluster(n_replicas=3)
+    devices = jax.devices()
+    for i, (_, eng) in enumerate(sorted(c.engines.items())):
+        assert eng.device == devices[i % len(devices)]
+    rng = np.random.default_rng(0)
+    for i in range(4):
+        c.submit([int(t) for t in rng.integers(1, 200, 40)],
+                 session_id=f"s{i}",
+                 params=SamplingParams(max_new_tokens=3))
+    c.run()
+    c.shutdown()
+    for i, (_, eng) in enumerate(sorted(c.engines.items())):
+        dev = devices[i % len(devices)]
+        for a in (jax.tree.leaves(eng.params)
+                  + jax.tree.leaves(eng.kv.pools)):
+            assert a.committed and a.devices() == {dev}
+    assert c.stats()["done"] == 4

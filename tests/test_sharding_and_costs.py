@@ -18,11 +18,8 @@ def test_logical_to_pspec():
 
 
 def _make_mesh():
-    """1x1 mesh across jax versions (AxisType landed after 0.4.37)."""
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-    return jax.make_mesh((1, 1), ("data", "model"), **kw)
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_evenly_shardable_drops_indivisible():

@@ -1,0 +1,118 @@
+"""Mosaic compiles of the serving kernels at published widths, for a
+described (not attached) TPU v5e: what the chip's compiler refuses —
+a vector-layout cast it cannot lower, more scoped VMEM than a kernel may
+use — fails here, without a chip.  Nothing runs; these tests say
+nothing about results or speed.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process may load the TPU library at a time, and the
+worker that runs this file keeps it until it exits."""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.configs.paper_models import DEEPSEEK_V3
+from repro.kernels.mla_paged_decode import mla_paged_decode
+from repro.kernels.paged_attention import (paged_decode_attention,
+                                           paged_decode_attention_int8)
+from repro.kernels.paged_prefill import (mla_paged_prefill_segments,
+                                         paged_prefill_attention,
+                                         paged_prefill_segments)
+
+BATCH, PAGE, PAGES, POOL, CHUNK = 8, 64, 32, 256, 64   # engine defaults
+GQA_ARCHS = ["llama3.2-1b", "glm4-9b"]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2.  A described-chip compile cannot
+    be read back from JAX's persistent cache without a chip, so the
+    cache is off while these tests run."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text        # the Mosaic kernel is there
+
+
+def _gqa(arch):
+    cfg = get_config(arch)
+    return cfg.n_heads, cfg.n_kv_heads, cfg.hd
+
+
+@pytest.mark.parametrize("arch", GQA_ARCHS)
+def test_paged_decode_compiles(arch, one_chip):
+    hq, hkv, hd = _gqa(arch)
+    _compile(lambda q, k, v, bt, ln: paged_decode_attention(
+        q, k, v, bt, ln, interpret=False), one_chip,
+        ((BATCH, hq, hd), jnp.bfloat16),
+        ((POOL, PAGE, hkv, hd), jnp.bfloat16),
+        ((POOL, PAGE, hkv, hd), jnp.bfloat16),
+        ((BATCH, PAGES), jnp.int32), ((BATCH,), jnp.int32))
+
+
+@pytest.mark.parametrize("arch", GQA_ARCHS)
+def test_paged_decode_int8_compiles(arch, one_chip):
+    hq, hkv, hd = _gqa(arch)
+    _compile(lambda q, k, v, ks, vs, bt, ln: paged_decode_attention_int8(
+        q, k, v, ks, vs, bt, ln, interpret=False), one_chip,
+        ((BATCH, hq, hd), jnp.bfloat16),
+        ((POOL, PAGE, hkv, hd), jnp.int8),
+        ((POOL, PAGE, hkv, hd), jnp.int8),
+        ((POOL, PAGE, hkv, 1), jnp.bfloat16),
+        ((POOL, PAGE, hkv, 1), jnp.bfloat16),
+        ((BATCH, PAGES), jnp.int32), ((BATCH,), jnp.int32))
+
+
+@pytest.mark.parametrize("arch", GQA_ARCHS)
+@pytest.mark.parametrize("kernel,positions", [
+    (paged_prefill_attention, (BATCH,)),          # scalar offsets
+    (paged_prefill_segments, (BATCH, CHUNK)),     # per-query positions
+], ids=["offsets", "segments"])
+def test_paged_prefill_compiles(arch, kernel, positions, one_chip):
+    hq, hkv, hd = _gqa(arch)
+    _compile(lambda q, kc, vc, kp, vp, bt, pos: kernel(
+        q, kc, vc, kp, vp, bt, pos, interpret=False), one_chip,
+        ((BATCH, CHUNK, hq, hd), jnp.bfloat16),
+        ((BATCH, CHUNK, hkv, hd), jnp.bfloat16),
+        ((BATCH, CHUNK, hkv, hd), jnp.bfloat16),
+        ((POOL, PAGE, hkv, hd), jnp.bfloat16),
+        ((POOL, PAGE, hkv, hd), jnp.bfloat16),
+        ((BATCH, PAGES), jnp.int32), (positions, jnp.int32))
+
+
+def test_mla_decode_compiles(one_chip):
+    hq, dl, dr = DEEPSEEK_V3.n_heads, DEEPSEEK_V3.d_latent, DEEPSEEK_V3.d_rope
+    _compile(lambda ql, qr, lp, bt, ln: mla_paged_decode(
+        ql, qr, lp, bt, ln, d_latent=dl, interpret=False), one_chip,
+        ((BATCH, hq, dl), jnp.bfloat16), ((BATCH, hq, dr), jnp.bfloat16),
+        ((POOL, PAGE, dl + dr), jnp.bfloat16),
+        ((BATCH, PAGES), jnp.int32), ((BATCH,), jnp.int32))
+
+
+def test_mla_prefill_segments_compiles(one_chip):
+    """At chunk 64 one step's q/out blocks and f32 state for all 128
+    heads exceed the 16 MiB scoped VMEM; the kernel tiles heads."""
+    hq, dl, dr = DEEPSEEK_V3.n_heads, DEEPSEEK_V3.d_latent, DEEPSEEK_V3.d_rope
+    _compile(lambda ql, qr, lc, lp, bt, cp: mla_paged_prefill_segments(
+        ql, qr, lc, lp, bt, cp, d_latent=dl, interpret=False), one_chip,
+        ((BATCH, CHUNK, hq, dl), jnp.bfloat16),
+        ((BATCH, CHUNK, hq, dr), jnp.bfloat16),
+        ((BATCH, CHUNK, dl + dr), jnp.bfloat16),
+        ((POOL, PAGE, dl + dr), jnp.bfloat16),
+        ((BATCH, PAGES), jnp.int32), ((BATCH, CHUNK), jnp.int32))

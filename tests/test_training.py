@@ -1,9 +1,5 @@
 """Training substrate: loss decreases, checkpoint/restart bit-exactness,
 resume equivalence (fault tolerance), gradient compression, ZeRO-1."""
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
