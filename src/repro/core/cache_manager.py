@@ -741,7 +741,9 @@ class PredictiveCacheManager:
 
     # ------------------------------------------------------------------
     def metrics(self) -> dict:
-        """Prometheus-style metrics (paper §IV Observability)."""
+        """The manager's counters, tier occupancy, predictor posteriors
+        and cost in one dict: ``ServingEngine.stats()["cache"]`` and the
+        cluster's per-replica stats read it (paper §IV Observability)."""
         self.sync_fault_stats()
         return {
             "hit_rate_hot": self.stats.hit_rate,

@@ -58,6 +58,7 @@ from repro.core.tiers import (TPU_V5E_TIER_SPECS, AsyncTierTransferWorker,
 from repro.kernels.backend import resolve_backend
 from repro.models.model import build_model
 from repro.serving import sampler as sampler_mod
+from repro.serving import spans
 from repro.serving.kvcache import PagedKVCache, SlotKVCache
 from repro.serving.request import Phase, Request, SamplingParams
 from repro.serving.scheduler import Scheduler, SchedulerConfig
@@ -114,6 +115,15 @@ class EngineConfig:
     #                                   per-request sampling dispatches, the
     #                                   pre-PR7 A/B path — greedy decode is
     #                                   token-identical either way)
+
+
+def _named(name: str, fn, *args, **kwargs):
+    """``functools.partial(fn, *args, **kwargs)`` named ``name``: jitted,
+    its program is ``jit_<name>`` (an unnamed partial lowers to
+    ``jit__unknown``)."""
+    f = functools.partial(fn, *args, **kwargs)
+    f.__name__ = name
+    return f
 
 
 def _fused_paged_step(model, backend, params, state, tokens, active, rng,
@@ -192,20 +202,20 @@ class ServingEngine:
                                    page_tokens=engine_cfg.page_tokens,
                                    device=device)
             self._decode = jax.jit(
-                functools.partial(self.model.decode_step_paged,
-                                  backend=self.kernel_backend),
+                _named("decode", self.model.decode_step_paged,
+                       backend=self.kernel_backend),
                 donate_argnums=(1,))
             self._fused_decode = jax.jit(
-                functools.partial(_fused_paged_step, self.model,
-                                  self.kernel_backend),
+                _named("fused_decode", _fused_paged_step, self.model,
+                       self.kernel_backend),
                 donate_argnums=(1,))
         else:
             self.kv = SlotKVCache(self.model, self.scheduler.n_slots,
                                   engine_cfg.max_len, device=device)
-            self._decode = jax.jit(self.model.decode_step,
+            self._decode = jax.jit(_named("decode", self.model.decode_step),
                                    donate_argnums=(1,))
             self._fused_decode = jax.jit(
-                functools.partial(_fused_dense_step, self.model),
+                _named("fused_decode", _fused_dense_step, self.model),
                 donate_argnums=(1,))
         # scale tier-0 capacity to the configured budget so eviction and
         # tier demotion actually engage at live-test scale (replay passes
@@ -229,17 +239,17 @@ class ServingEngine:
         self.chunked = (engine_cfg.chunked_prefill and self.paged
                         and self.model.supports_chunked_prefill())
         self._rng = jax.random.PRNGKey(engine_cfg.seed + 1)
-        self._prefill = jax.jit(self.model.prefill)
+        self._prefill = jax.jit(_named("prefill", self.model.prefill))
         self._prefill_chunk = jax.jit(
-            functools.partial(self.model.prefill_chunk,
-                              backend=self.kernel_backend))
+            _named("prefill_chunk", self.model.prefill_chunk,
+                   backend=self.kernel_backend))
         # segment reuse needs the chunked paged path: resumed mid-prompt
         # islands are CoW-mapped / injected into the block table and the
         # gaps between them prefill through the position-explicit kernel
         self.seg_enabled = engine_cfg.segment_reuse and self.chunked
         self._prefill_chunk_seg = (jax.jit(
-            functools.partial(self.model.prefill_chunk_seg,
-                              backend=self.kernel_backend))
+            _named("prefill_chunk_seg", self.model.prefill_chunk_seg,
+                   backend=self.kernel_backend))
             if self.chunked else None)
         # request_id -> [payload | None, length]; payload is the staging
         # buffer — dropped once the async demotion write lands
@@ -273,6 +283,8 @@ class ServingEngine:
         self.segment_chunks = 0        # position-explicit kernel chunks
         self.last_step_prefill_tokens = 0
         self.max_step_prefill_tokens = 0   # budget-compliance witness
+        self.decode_rows = 0           # rows decoded, summed over steps
+        self.decode_ctx_tokens = 0     # tokens those rows attended
 
     def _on_device(self):
         """Context in which arrays this engine creates land on its
@@ -457,6 +469,8 @@ class ServingEngine:
 
         # monolithic fallback (dense layout / --no-chunked A/B): prefill
         # the whole unmatched suffix in one forward
+        if req.t_prefill_start is None:
+            req.t_prefill_start = time.monotonic()
         suffix = list(effective[prefix_len:])
         pad = self.ecfg.pad_prefill_to
         padded_len = max(pad, ((len(suffix) + pad - 1) // pad) * pad)
@@ -475,6 +489,7 @@ class ServingEngine:
             self.kv.write_range(slot, state1, prefix_len, padded_len)
         # true sequence length (padding tokens are masked by length)
         self.kv.set_length(slot, len(effective))
+        self._stamp_prefill_done(req)
         self._register_prompt_blocks(req, slot, effective)
         self.scheduler.start(req, slot)
 
@@ -485,24 +500,28 @@ class ServingEngine:
         mgr = self.manager
         bt = mgr.block_tokens
         n_full = (len(effective) // bt) * bt
-        new_ids = mgr.register_sequence(
-            list(effective[:n_full]), block_type=req.block_type,
-            block_types=req.block_types,
-            recompute_cost_per_block=self._block_recompute_cost())
-        for i, bid in enumerate(new_ids):
-            if bid not in mgr._payloads:
-                pl = self.kv.extract_block(slot, i * bt, bt)
-                mgr._payloads[bid] = pl
-                # registration admitted the block metadata-first; give its
-                # tier copy the real bytes so demotions checksum and move
-                # actual payloads, not placeholders
-                mgr.hierarchy.attach_payload(bid, pl)
-            if self.paged:
-                self.kv.register_block_pages(bid, slot, i * bt, bt)
-            if mgr.fleet_bound:
-                # publish-on-register: the block (payload included) joins
-                # the fleet-shared tier so sibling replicas can import it
-                mgr.publish_block(bid)
+        with spans.span(spans.REGISTER_BLOCKS, rid=req.request_id,
+                        blocks=n_full // bt):
+            new_ids = mgr.register_sequence(
+                list(effective[:n_full]), block_type=req.block_type,
+                block_types=req.block_types,
+                recompute_cost_per_block=self._block_recompute_cost())
+            for i, bid in enumerate(new_ids):
+                if bid not in mgr._payloads:
+                    with spans.span(spans.BLOCK_TO_HOST):
+                        pl = self.kv.extract_block(slot, i * bt, bt)
+                    mgr._payloads[bid] = pl
+                    # registration admitted the block metadata-first; give
+                    # its tier copy the real bytes so demotions checksum
+                    # and move actual payloads, not placeholders
+                    mgr.hierarchy.attach_payload(bid, pl)
+                if self.paged:
+                    self.kv.register_block_pages(bid, slot, i * bt, bt)
+                if mgr.fleet_bound:
+                    # publish-on-register: the block (payload included)
+                    # joins the fleet-shared tier so sibling replicas can
+                    # import it
+                    mgr.publish_block(bid)
         req.block_ids = new_ids
         if new_ids:
             self._block_epoch += 1
@@ -510,9 +529,19 @@ class ServingEngine:
     # ------------------------------------------------------------------
     # chunked prefill (token-budget mixed batches)
     # ------------------------------------------------------------------
+    @staticmethod
+    def _stamp_prefill_done(req: Request) -> None:
+        """The first time ``req``'s prompt is wholly in the cache; a
+        prompt served from cache alone started its prefill then too."""
+        if req.t_prefill_done is None:
+            req.t_prefill_done = time.monotonic()
+            if req.t_prefill_start is None:
+                req.t_prefill_start = req.t_prefill_done
+
     def _finish_prefill(self, req: Request) -> None:
         """Chunk cursor reached the prompt end: register prompt blocks
         and transition PREFILL -> DECODE."""
+        self._stamp_prefill_done(req)
         self._register_prompt_blocks(req, req.slot, req.prefill_tokens)
         self._prefix_checked.pop(req.request_id, None)
         self._admit_transition.pop(req.request_id, None)
@@ -611,12 +640,16 @@ class ServingEngine:
         if n == 0:
             return 0
         toks = req.prefill_tokens
-        chunk = [toks[p] for p in positions]
-        arr = jnp.asarray([chunk + [0] * (C - n)], jnp.int32)
-        cpos = jnp.asarray([positions + [-1] * (C - n)], jnp.int32)
-        state1 = self._prefill_chunk_seg(
-            self.params, self.kv.chunk_state(req.slot), arr, cpos)
-        self.kv.write_chunk_positions(req.slot, state1, positions)
+        with spans.span(spans.PREFILL_CHUNK, rid=req.request_id,
+                        start=positions[0], tokens=n):
+            if req.t_prefill_start is None:
+                req.t_prefill_start = time.monotonic()
+            chunk = [toks[p] for p in positions]
+            arr = jnp.asarray([chunk + [0] * (C - n)], jnp.int32)
+            cpos = jnp.asarray([positions + [-1] * (C - n)], jnp.int32)
+            state1 = self._prefill_chunk_seg(
+                self.params, self.kv.chunk_state(req.slot), arr, cpos)
+            self.kv.write_chunk_positions(req.slot, state1, positions)
         # every gap below positions[-1] is now filled, so the contiguous
         # frontier advances to just past it (then over any island there)
         req.prefill_pos = positions[-1] + 1
@@ -646,12 +679,18 @@ class ServingEngine:
                 done += n
             else:
                 n = min(C, n_tokens - done, len(toks) - req.prefill_pos)
-                chunk = list(toks[req.prefill_pos:req.prefill_pos + n])
-                arr = jnp.asarray([chunk + [0] * (C - n)], jnp.int32)
-                off = jnp.asarray([req.prefill_pos], jnp.int32)
-                state1 = self._prefill_chunk(
-                    self.params, self.kv.chunk_state(req.slot), arr, off)
-                self.kv.write_chunk(req.slot, state1, req.prefill_pos, n)
+                with spans.span(spans.PREFILL_CHUNK, rid=req.request_id,
+                                start=req.prefill_pos, tokens=n):
+                    if req.t_prefill_start is None:
+                        req.t_prefill_start = time.monotonic()
+                    chunk = list(toks[req.prefill_pos:req.prefill_pos + n])
+                    arr = jnp.asarray([chunk + [0] * (C - n)], jnp.int32)
+                    off = jnp.asarray([req.prefill_pos], jnp.int32)
+                    state1 = self._prefill_chunk(
+                        self.params, self.kv.chunk_state(req.slot), arr,
+                        off)
+                    self.kv.write_chunk(req.slot, state1, req.prefill_pos,
+                                        n)
                 req.prefill_pos += n
                 done += n
                 self.prefill_chunks += 1
@@ -714,8 +753,7 @@ class ServingEngine:
         if loc is None:
             # demoted copy lost entirely: recompute path
             self._preempted_payloads.pop(req.request_id, None)
-            slot = self.kv.acquire(req.request_id, req.prompt_len)
-            self._admit(req, slot)
+            self._admit(req, self._acquire_slot(req))
             return
         self.scheduler.block_on_transfer(req)
         self.worker.submit(TransferRequest(
@@ -766,38 +804,46 @@ class ServingEngine:
         upload); any host-side mutation (admission, prefill write, CoW
         copy, release, page-boundary crossing) triggers a rebuild via
         ``PagedKVCache.state_version``."""
-        sa = self.scheduler.step_arrays(decode_reqs, self.kv.n_slots)
-        self._rng, step_key = jax.random.split(self._rng)
-        if self.paged:
-            slots = [r.slot for r in decode_reqs]
-            state = self.kv.decode_state(slots, reuse=True)
-            toks, new_state = self._fused_decode(
-                self.params, state, jnp.asarray(sa["tokens"]),
-                jnp.asarray(sa["active"]), step_key,
-                jnp.asarray(sa["temperature"]), jnp.asarray(sa["top_k"]),
-                jnp.asarray(sa["top_p"]))
-            self.kv.absorb(new_state, decode_slots=slots)
-        else:
-            toks, self.kv.state = self._fused_decode(
-                self.params, self.kv.state, jnp.asarray(sa["tokens"]),
-                step_key, jnp.asarray(sa["temperature"]),
-                jnp.asarray(sa["top_k"]), jnp.asarray(sa["top_p"]))
-        out = np.asarray(toks)     # single sync point for the step
-        now = time.monotonic()
-        produced = 0
-        prefetch = []
-        for req in sorted(decode_reqs, key=lambda r: r.slot):
-            req.generated.append(int(out[req.slot]))
-            if req.t_first_token is None:
-                req.t_first_token = now
-            produced += 1
-            self.kv.advance(req.slot)
-            if req.block_ids:
-                prefetch.append((req.block_ids,
-                                 self.kv.slots[req.slot].length))
-        # RoPE prefetch promotions, planned once per step under one
-        # manager lock (async when the transfer worker is on)
-        self._submit_prefetch_many(prefetch)
+        with spans.span(spans.DECODE_PREPARE) as sp:
+            sa = self.scheduler.step_arrays(decode_reqs, self.kv.n_slots)
+            self._rng, step_key = jax.random.split(self._rng)
+            sampling = (step_key, jnp.asarray(sa["temperature"]),
+                        jnp.asarray(sa["top_k"]), jnp.asarray(sa["top_p"]))
+            tokens = jnp.asarray(sa["tokens"])
+            if self.paged:
+                slots = [r.slot for r in decode_reqs]
+                rebuilds = self.kv.state_rebuilds
+                state = self.kv.decode_state(slots, reuse=True)
+                active = jnp.asarray(sa["active"])
+                sp.set_metadata(
+                    rebuilt=int(self.kv.state_rebuilds > rebuilds))
+        with spans.span(spans.DECODE_DISPATCH):
+            if self.paged:
+                toks, new_state = self._fused_decode(
+                    self.params, state, tokens, active, *sampling)
+                self.kv.absorb(new_state, decode_slots=slots)
+            else:
+                toks, self.kv.state = self._fused_decode(
+                    self.params, self.kv.state, tokens, *sampling)
+        with spans.span(spans.DECODE_WAIT):
+            out = np.asarray(toks)     # single sync point for the step
+        with spans.span(spans.DECODE_FINISH):
+            now = time.monotonic()
+            produced = 0
+            prefetch = []
+            for req in sorted(decode_reqs, key=lambda r: r.slot):
+                req.generated.append(int(out[req.slot]))
+                if req.t_first_token is None:
+                    req.t_first_token = now
+                produced += 1
+                self.kv.advance(req.slot)
+                if req.block_ids:
+                    prefetch.append((req.block_ids,
+                                     self.kv.slots[req.slot].length))
+            # RoPE prefetch promotions, planned once per step under one
+            # manager lock (async when the transfer worker is on)
+            self._submit_prefetch_many(prefetch)
+            self._finish_rows(decode_reqs)
         return produced
 
     def _decode_unfused(self, decode_reqs) -> int:
@@ -839,7 +885,21 @@ class ServingEngine:
             if req.block_ids:
                 self._submit_prefetch(req.block_ids,
                                       self.kv.slots[slot].length)
+        self._finish_rows(decode_reqs)
         return produced
+
+    def _finish_rows(self, decode_reqs) -> None:
+        """Finish the decoded rows that are done (lengths already
+        advanced): release their blocks to the manager and their slots."""
+        for req in decode_reqs:
+            if req.finished() or req.total_len >= self.ecfg.max_len - 1:
+                # retain_blocks (session continuation) balances the dedup
+                # refcount but keeps the blocks registered for the next
+                # turn's prefix match
+                self.manager.release_sequence(req.block_ids,
+                                              retain=req.retain_blocks)
+                self.scheduler.finish(req)
+                self.kv.release(req.slot)
 
     # ------------------------------------------------------------------
     def step(self) -> int:
@@ -849,56 +909,62 @@ class ServingEngine:
         with self._on_device():
             return self._step()
 
+    def _acquire_slot(self, req: Request) -> int:
+        slot = self.kv.acquire(req.request_id, req.prompt_len)
+        if req.t_slot is None:
+            req.t_slot = time.monotonic()
+        return slot
+
     def _step(self) -> int:
         sch = self.scheduler
-        # completion events (scheduler polls; engine interprets)
-        self._poll_transfers()
-        # straggler handling
-        for req in sch.check_stragglers():
-            self.preempt(req)
-        # admission
-        for req in sch.admissible(len(self.kv.free_slots())):
-            ent = self._preempted_payloads.get(req.request_id)
-            if ent is not None and ent[0] is None:
-                self._begin_async_restore(req)
-                continue
-            slot = self.kv.acquire(req.request_id, req.prompt_len)
-            self._admit(req, slot)
-        if not sch.running:
-            return 0
-        # budget-select the mixed batch: the decode set is snapshotted
-        # *before* prefill runs, so this step's token count is bounded
-        # by max_step_tokens even when a chunk finishes a prompt
-        decode_reqs, grants = sch.plan_step()
-        prefill_tokens = 0
-        for req, n in grants:
-            prefill_tokens += self._run_prefill_chunks(req, n)
-        self.last_step_prefill_tokens = prefill_tokens
-        self.max_step_prefill_tokens = max(self.max_step_prefill_tokens,
-                                           prefill_tokens)
-        self.prefill_tokens_total += prefill_tokens
-        produced = 0
-        if decode_reqs:
-            produced = (self._decode_fused(decode_reqs) if self.fused
-                        else self._decode_unfused(decode_reqs))
-            # lengths already advanced; sync infos + finish bookkeeping
-            for req in decode_reqs:
-                if (req.finished()
-                        or req.total_len >= self.ecfg.max_len - 1):
-                    # retain_blocks (session continuation) balances the
-                    # dedup refcount but keeps the blocks registered for
-                    # the next turn's prefix match
-                    self.manager.release_sequence(
-                        req.block_ids, retain=req.retain_blocks)
-                    sch.finish(req)
-                    self.kv.release(req.slot)
-        if self.paged:
-            # unpin pages of blocks the manager demoted or dropped
-            self.kv.gc_blocks(self.manager)
-        self.manager.tick()
-        self.manager.age_all()
-        self.steps += 1
-        return produced
+        with spans.span(spans.ITERATION):
+            with spans.span(spans.POLL):
+                # completion events (scheduler polls; engine interprets)
+                self._poll_transfers()
+                # straggler handling
+                for req in sch.check_stragglers():
+                    self.preempt(req)
+            with spans.span(spans.ADMISSION):
+                for req in sch.admissible(len(self.kv.free_slots())):
+                    ent = self._preempted_payloads.get(req.request_id)
+                    if ent is not None and ent[0] is None:
+                        self._begin_async_restore(req)
+                        continue
+                    self._admit(req, self._acquire_slot(req))
+            if not sch.running:
+                return 0
+            # budget-select the mixed batch: the decode set is snapshotted
+            # *before* prefill runs, so this step's token count is bounded
+            # by max_step_tokens even when a chunk finishes a prompt
+            decode_reqs, grants = sch.plan_step()
+            prefill_tokens = 0
+            with spans.span(spans.PREFILL):
+                for req, n in grants:
+                    req.prefill_steps += 1
+                    prefill_tokens += self._run_prefill_chunks(req, n)
+            self.last_step_prefill_tokens = prefill_tokens
+            self.max_step_prefill_tokens = max(self.max_step_prefill_tokens,
+                                               prefill_tokens)
+            self.prefill_tokens_total += prefill_tokens
+            produced = 0
+            if decode_reqs:
+                rows = len(decode_reqs)
+                ctx = rows + sum(self.kv.slots[r.slot].length
+                                 for r in decode_reqs)
+                self.decode_rows += rows
+                self.decode_ctx_tokens += ctx
+                with spans.span(spans.DECODE, rows=rows, ctx_tokens=ctx):
+                    produced = (self._decode_fused(decode_reqs)
+                                if self.fused
+                                else self._decode_unfused(decode_reqs))
+            with spans.span(spans.CACHE_UPKEEP):
+                if self.paged:
+                    # unpin pages of blocks the manager demoted or dropped
+                    self.kv.gc_blocks(self.manager)
+                self.manager.tick()
+                self.manager.age_all()
+            self.steps += 1
+            return produced
 
     def preempt(self, req: Request) -> None:
         """Demote a running request's KV into the tier hierarchy —
@@ -983,7 +1049,13 @@ class ServingEngine:
                "segment_reuse": self.seg_enabled,
                "segment_share_hits": self.segment_share_hits,
                "segment_inject_hits": self.segment_inject_hits,
-               "segment_chunks": self.segment_chunks}
+               "segment_chunks": self.segment_chunks,
+               "decode_rows": self.decode_rows,
+               "decode_ctx_tokens": self.decode_ctx_tokens,
+               "d2h_bytes": self.kv.d2h_bytes,
+               "d2h_wait_s": self.kv.d2h_wait_s,
+               "d2h_copy_s": self.kv.d2h_copy_s,
+               "h2d_bytes": self.kv.h2d_bytes}
         if self.paged:
             out["allocator"] = self.kv.allocator.stats_dict()
             out["decode_state_reuses"] = self.kv.state_reuses

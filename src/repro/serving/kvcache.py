@@ -27,6 +27,7 @@ so tier demotion/promotion is layout-agnostic.
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -76,6 +77,12 @@ class _KVCacheBase:
 
     cfg: ModelConfig
     slots: List[SlotInfo]
+    # block copies between device and host, for ``ServingEngine.stats()``
+    d2h_bytes = 0         # bytes copied to host (``extract_block``)
+    d2h_wait_s = 0.0      # ... waiting for the device work queued ahead
+    d2h_copy_s = 0.0      # ... copying
+    h2d_bytes = 0         # payload bytes injected (its time is on the
+    #                       device trace; timing it here would add a sync)
 
     # -- slots --------------------------------------------------------------
     def free_slots(self) -> List[int]:
@@ -104,6 +111,7 @@ class _KVCacheBase:
                      start: int) -> int:
         """Write one reused block payload at token offset `start`."""
         n = payload.shape[2]
+        self.h2d_bytes += payload.nbytes
         self.write_range(slot, self._payload_state(payload), start, n)
         return n
 
@@ -115,6 +123,18 @@ class _KVCacheBase:
         suffix are never written)."""
         self.write_range(slot, state1, offset, n_tokens)
         self.set_length(slot, offset + n_tokens)
+
+    def _to_host(self, *arrays) -> List[np.ndarray]:
+        """Device slices -> numpy.  The wait for the device work queued
+        ahead of them is timed apart from the copy itself."""
+        t0 = time.perf_counter()
+        jax.block_until_ready(arrays)
+        t1 = time.perf_counter()
+        out = [np.asarray(a) for a in arrays]
+        self.d2h_copy_s += time.perf_counter() - t1
+        self.d2h_wait_s += t1 - t0
+        self.d2h_bytes += sum(a.nbytes for a in out)
+        return out
 
     # -- preemption ---------------------------------------------------------
     def evict_slot_to_payload(self, slot: int) -> Tuple[np.ndarray, int]:
@@ -183,10 +203,11 @@ class SlotKVCache(_KVCacheBase):
         """Slot KV -> block payload [2, L, n_tokens, H, hd] (or MLA
         [1, L, n_tokens, dl+dr])."""
         if self.cfg.attention_variant == MLA:
-            lat = self.state["latent"][:, slot, start:start + n_tokens]
-            return np.asarray(lat)[None]
-        k = np.asarray(self.state["k"][:, slot, start:start + n_tokens])
-        v = np.asarray(self.state["v"][:, slot, start:start + n_tokens])
+            lat, = self._to_host(
+                self.state["latent"][:, slot, start:start + n_tokens])
+            return lat[None]
+        k, v = self._to_host(self.state["k"][:, slot, start:start + n_tokens],
+                             self.state["v"][:, slot, start:start + n_tokens])
         return np.stack([k, v])
 
     def prefix_kv(self, slot: int, length: int):
@@ -467,10 +488,11 @@ class PagedKVCache(_KVCacheBase):
     def extract_block(self, slot: int, start: int,
                       n_tokens: int) -> np.ndarray:
         if self.mla:
-            lat = self._gather("latent_pages", slot, start, n_tokens)
-            return np.asarray(lat)[None]
-        k = np.asarray(self._gather("k_pages", slot, start, n_tokens))
-        v = np.asarray(self._gather("v_pages", slot, start, n_tokens))
+            lat, = self._to_host(
+                self._gather("latent_pages", slot, start, n_tokens))
+            return lat[None]
+        k, v = self._to_host(self._gather("k_pages", slot, start, n_tokens),
+                             self._gather("v_pages", slot, start, n_tokens))
         return np.stack([k, v])
 
     def prefix_kv(self, slot: int, length: int):

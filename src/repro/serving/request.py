@@ -67,6 +67,15 @@ class Request:
     # final token) and the per-request chunk cursor into them
     prefill_tokens: Optional[List[int]] = None
     prefill_pos: int = 0
+    # lifecycle stamps (time.monotonic, the clock of ``arrival``); a
+    # restore after preemption keeps the first of each
+    t_slot: Optional[float] = None           # slot acquired
+    t_prefill_start: Optional[float] = None  # first prefill chunk
+    #                                          dispatched (= t_prefill_done
+    #                                          for a prompt served from cache)
+    t_prefill_done: Optional[float] = None   # prompt wholly in the cache
+    prefill_steps: int = 0                   # steps that granted it prompt
+    #                                          tokens
     t_first_token: Optional[float] = None
     t_done: Optional[float] = None
 
@@ -109,6 +118,10 @@ class Request:
         self.seg_spans = []
         self.prefill_tokens = None
         self.prefill_pos = 0
+        self.t_slot = None
+        self.t_prefill_start = None
+        self.t_prefill_done = None
+        self.prefill_steps = 0
         self.t_first_token = None
         self.t_done = None
 
