@@ -285,6 +285,9 @@ class ServingEngine:
         self.max_step_prefill_tokens = 0   # budget-compliance witness
         self.decode_rows = 0           # rows decoded, summed over steps
         self.decode_ctx_tokens = 0     # tokens those rows attended
+        self.decode_sampled_steps = 0  # fused decode steps with a row at
+        #                                temperature > 0 (the sampler's
+        #                                stochastic branch ran)
 
     def _on_device(self):
         """Context in which arrays this engine creates land on its
@@ -806,6 +809,10 @@ class ServingEngine:
         ``PagedKVCache.state_version``."""
         with spans.span(spans.DECODE_PREPARE) as sp:
             sa = self.scheduler.step_arrays(decode_reqs, self.kv.n_slots)
+            # idle slots hold temperature 0, so this is "some active row
+            # samples": the step takes the sampler's stochastic branch
+            sampled = bool((sa["temperature"] > 0.0).any())
+            self.decode_sampled_steps += sampled
             self._rng, step_key = jax.random.split(self._rng)
             sampling = (step_key, jnp.asarray(sa["temperature"]),
                         jnp.asarray(sa["top_k"]), jnp.asarray(sa["top_p"]))
@@ -817,6 +824,7 @@ class ServingEngine:
                 active = jnp.asarray(sa["active"])
                 sp.set_metadata(
                     rebuilt=int(self.kv.state_rebuilds > rebuilds))
+            sp.set_metadata(sampled=int(sampled))
         with spans.span(spans.DECODE_DISPATCH):
             if self.paged:
                 toks, new_state = self._fused_decode(
@@ -1051,6 +1059,7 @@ class ServingEngine:
                "segment_inject_hits": self.segment_inject_hits,
                "segment_chunks": self.segment_chunks,
                "decode_rows": self.decode_rows,
+               "decode_sampled_steps": self.decode_sampled_steps,
                "decode_ctx_tokens": self.decode_ctx_tokens,
                "d2h_bytes": self.kv.d2h_bytes,
                "d2h_wait_s": self.kv.d2h_wait_s,

@@ -41,29 +41,41 @@ def sample_batched(logits: jax.Array, rng: jax.Array,
     top_k <= 0 and top_p >= 1 disable those filters.  Stochastic rows
     draw from ``jax.random.fold_in(rng, row)`` — a different key stream
     than the unfused path's sequential splits, same distribution.
+
+    Only a batch with a stochastic row runs the filters and the draw
+    (``lax.cond`` on the device); an all-greedy batch returns the
+    ``argmax`` alone.
     """
     B, V = logits.shape
     lg32 = logits.astype(jnp.float32)
     greedy = jnp.argmax(lg32, axis=-1).astype(jnp.int32)
-    # greedy rows divide by 1e-6 here and are overridden below; logits
-    # are O(10) so the scaled values stay finite
-    lg = lg32 / jnp.maximum(temperature, 1e-6)[:, None]
-    # top-k: drop everything below the kth-largest (k = V disables)
-    k = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
-    kth = jnp.take_along_axis(jnp.sort(lg, axis=-1), (V - k)[:, None],
-                              axis=-1)
-    lg = jnp.where(lg < kth, -jnp.inf, lg)
-    # top-p: drop everything below the nucleus cutoff (p >= 1 keeps all
-    # mass — the cutoff lands at the smallest kept value)
-    sorted_lg = jnp.sort(lg, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_lg, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    cutoff_idx = jnp.minimum(jnp.sum(cum < top_p[:, None], axis=-1), V - 1)
-    cutoff = jnp.take_along_axis(sorted_lg, cutoff_idx[:, None], axis=-1)
-    lg = jnp.where(lg < cutoff, -jnp.inf, lg)
-    keys = jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(B))
-    sampled = jax.vmap(jax.random.categorical)(keys, lg).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+
+    def stochastic(lg32, greedy):
+        # greedy rows divide by 1e-6 here and are overridden below;
+        # logits are O(10) so the scaled values stay finite
+        lg = lg32 / jnp.maximum(temperature, 1e-6)[:, None]
+        # top-k: drop everything below the kth-largest (k = V disables)
+        k = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
+        kth = jnp.take_along_axis(jnp.sort(lg, axis=-1), (V - k)[:, None],
+                                  axis=-1)
+        lg = jnp.where(lg < kth, -jnp.inf, lg)
+        # top-p: drop everything below the nucleus cutoff (p >= 1 keeps
+        # all mass — the cutoff lands at the smallest kept value)
+        sorted_lg = jnp.sort(lg, axis=-1)[:, ::-1]
+        probs = jax.nn.softmax(sorted_lg, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        cutoff_idx = jnp.minimum(jnp.sum(cum < top_p[:, None], axis=-1),
+                                 V - 1)
+        cutoff = jnp.take_along_axis(sorted_lg, cutoff_idx[:, None], axis=-1)
+        lg = jnp.where(lg < cutoff, -jnp.inf, lg)
+        keys = jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(B))
+        sampled = jax.vmap(jax.random.categorical)(keys, lg).astype(jnp.int32)
+        return jnp.where(temperature <= 0.0, greedy, sampled)
+
+    # idle slots are greedy too; the predicate stays on the device, so
+    # there is no sync and the program stays one compiled variant
+    return jax.lax.cond(jnp.any(temperature > 0.0), stochastic,
+                        lambda lg32, greedy: greedy, lg32, greedy)
 
 
 def make_sampler(params: SamplingParams):

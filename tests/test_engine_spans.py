@@ -113,6 +113,9 @@ def test_span_args(traced):
     rebuilt = [s["rebuilt"] for n, _, _, s in traced
                if n == spans.DECODE_PREPARE]
     assert rebuilt[0] == 1 and rebuilt[1:] == [0] * (NEW - 1)
+    sampled = [s["sampled"] for n, _, _, s in traced
+               if n == spans.DECODE_PREPARE]
+    assert sampled == [0] * NEW             # greedy: no stochastic branch
     reg, = [s for n, _, _, s in traced if n == spans.REGISTER_BLOCKS]
     assert reg["blocks"] == 2
     assert sum(n == spans.BLOCK_TO_HOST for n, _, _, _ in traced) == 2
