@@ -1,4 +1,6 @@
-"""Seeded weights and the plain reference of a dense GQA decoder.
+"""The dense GQA decoder: seeded weights, the plain reference, the
+operations and bytes of its attention kernels and steps, and the names
+of those kernels in the device trace.
 
 Nothing here imports the program.  ``init_weights`` makes the weights
 from the seed in one jitted call, on the device and in bfloat16, in the
@@ -18,6 +20,14 @@ so a 4096-token sequence fits beside the weights.  ``quant="fp8"``
 rounds every matmul weight to float8 (e4m3, one scale per output
 column) first: the control, one precision below the bfloat16 the
 configuration is served in.
+
+The counts take what a call must compute and move, not what a kernel
+happens to fetch: the keys and values of each active row's valid tokens,
+one read of each weight per program.  A kernel that reads more than it
+needs therefore shows as a share of its roofline below 100%.  ``d``
+holds the sizes of ``dims``: L layers, width D, Hq query and Hkv KV
+heads of hd, feed-forward F, vocabulary V.  Activations and the KV cache
+are bfloat16.
 """
 from __future__ import annotations
 
@@ -28,7 +38,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
+from archlib import BF16, HIGHEST, _rms, _w, weight_key
+
+DECODE_KERNEL = "_paged_decode_pallas"   # op kinds in the device trace
+PREFILL_KERNEL = "_paged_prefill"        # (a pattern: any op holding it)
 Q_BLOCK = 512          # query rows per attention block
 BUCKET = 1024          # sequences are padded to a multiple of this
 BIAS_STD = 0.1         # enough that a dropped bias shows
@@ -42,13 +55,6 @@ def dims(model: dict) -> dict:
                 F=model["d_ff"], V=model["vocab_size"],
                 theta=float(model["rope_theta"]),
                 eps=float(model["norm_eps"]))
-
-
-def weight_key(seed: int) -> jax.Array:
-    """A PRNG key from any whole-number seed (the driver's exceed 32
-    bits)."""
-    return jax.random.PRNGKey(
-        int(np.random.default_rng(seed).integers(0, 2**31 - 1)))
 
 
 def init_weights(d: dict, seed: int):
@@ -95,25 +101,6 @@ def init_weights(d: dict, seed: int):
 # ---------------------------------------------------------------------------
 # the reference forward
 # ---------------------------------------------------------------------------
-def _fp8(w: jax.Array, axis) -> jax.Array:
-    """Round to float8 e4m3 with one scale per output column (``axis``
-    are the contracted axes)."""
-    w = w.astype(jnp.float32)
-    s = jnp.max(jnp.abs(w), axis=axis, keepdims=True) / 448.0
-    s = jnp.where(s == 0, 1.0, s)
-    return (w / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-def _w(w, quant, axis):
-    w = w.astype(jnp.float32)
-    return _fp8(w, axis) if quant == "fp8" else w
-
-
-def _rms(x, g, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
-        * g.astype(jnp.float32)
-
-
 def _rope(x, pos, theta):
     """x [S, H, hd]; rotate pairs (i, i + hd/2) by pos * theta^(-2i/hd)."""
     hd = x.shape[-1]
@@ -183,11 +170,51 @@ def logits(params, d: dict, tokens, rows, quant: str = "none") -> np.ndarray:
     return np.asarray(out, np.float32)
 
 
-def served_gaps(ref: np.ndarray, tokens) -> np.ndarray:
-    """How far each served token's reference logit lies below the
-    reference's best, in units of that position's logit spread."""
-    ref = np.asarray(ref, np.float64)
-    tok = np.asarray(tokens)
-    best = ref.max(axis=1)
-    got = ref[np.arange(len(tok)), tok]
-    return (best - got) / ref.std(axis=1)
+# ---------------------------------------------------------------------------
+# operations and bytes, from shapes alone
+# ---------------------------------------------------------------------------
+def layer_matmul_params(d: dict) -> int:
+    """Weights one token multiplies by in one layer (projections and
+    the SwiGLU feed-forward)."""
+    D, Hq, Hkv, hd, F = d["D"], d["Hq"], d["Hkv"], d["hd"], d["F"]
+    return 2 * D * Hq * hd + 2 * D * Hkv * hd + 3 * D * F
+
+
+def kv_bytes_per_token_layer(d: dict) -> int:
+    return 2 * d["Hkv"] * d["hd"] * BF16
+
+
+def decode_attention(d: dict, n_rows: int, ctx_sum: int):
+    """(flops, bytes) of the paged decode attention of one layer for a
+    batch of ``n_rows`` rows that attend ``ctx_sum`` tokens together."""
+    Hq, hd = d["Hq"], d["hd"]
+    flops = 4 * Hq * hd * ctx_sum
+    nbytes = (ctx_sum * kv_bytes_per_token_layer(d)
+              + 2 * n_rows * Hq * hd * BF16)
+    return flops, nbytes
+
+
+def prefill_attention(d: dict, start: int, n: int):
+    """(flops, bytes) of the paged prefill attention of one layer for a
+    chunk of ``n`` queries at positions start .. start+n-1, each
+    attending every token up to and including its own."""
+    Hq, hd = d["Hq"], d["hd"]
+    keys = n * start + n * (n + 1) // 2       # sum over queries of p + 1
+    flops = 4 * Hq * hd * keys
+    nbytes = ((start + n) * kv_bytes_per_token_layer(d)
+              + 2 * n * Hq * hd * BF16)
+    return flops, nbytes
+
+
+def decode_step_flops(d: dict, n_rows: int, ctx_sum: int) -> int:
+    """Model flops of one decode step: every layer's matmuls and
+    attention, and the output head, for each row."""
+    mm = 2 * n_rows * (d["L"] * layer_matmul_params(d) + d["D"] * d["V"])
+    return mm + d["L"] * decode_attention(d, n_rows, ctx_sum)[0]
+
+
+def prefill_chunk_flops(d: dict, start: int, n: int) -> int:
+    """Model flops of one prefill chunk: every layer's matmuls and
+    attention for ``n`` tokens (a chunk computes no logits)."""
+    mm = 2 * n * d["L"] * layer_matmul_params(d)
+    return mm + d["L"] * prefill_attention(d, start, n)[0]

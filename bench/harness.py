@@ -1,9 +1,12 @@
 """Where the benchmark finds things by name: ``BENCHMARK.json`` at the
-root of the checkout, a configuration in ``configs/<config>.json``, a
-traffic mix in ``traffic/<mix>.json``, a cell's load in
-``cells/<cell>.json``, one reader per metric in ``metrics/<metric>.py``
-and the chip's peaks in ``peaks.json``.  Adding a cell or a metric adds
-files; nothing here names one."""
+root of the checkout, a configuration in ``configs/<config>.json``, the
+architecture that configuration names in ``arch/<arch>.py`` (its
+seeded weights, plain reference, cost functions and kernel names), a
+traffic mix in ``traffic/<mix>.json``, a cell's load, limits and
+replicas in ``cells/<cell>.json``, one reader per metric in
+``metrics/<metric>.py`` and the chip's peaks in ``peaks.json``.  Adding
+a cell, a metric or an architecture adds files; nothing here names
+one."""
 from __future__ import annotations
 
 import importlib.util
@@ -49,14 +52,44 @@ def load_peaks(device_kind: str) -> dict:
     return table["devices"][device_kind]
 
 
-def load_reader(metric: str):
-    """The ``read(ctx)`` function of ``metrics/<metric>.py``."""
-    path = BENCH / "metrics" / f"{metric}.py"
+def _module(kind: str, name: str, path: Path):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str):
+    """The ``read(ctx)`` function of ``metrics/<metric>.py``."""
+    return _module("metric", metric,
+                   BENCH / "metrics" / f"{metric}.py").read
+
+
+ARCH_DIRS = [BENCH / "arch"]      # where architecture modules are found
+_ARCHS: dict = {}                 # path -> module, loaded once a process
+
+
+def load_arch(name: str):
+    """The module ``arch/<name>.py``, loaded once, so that its jitted
+    reference compiles once however many runs a process makes."""
+    for d in ARCH_DIRS:
+        path = d / f"{name}.py"
+        if path.is_file():
+            if path not in _ARCHS:
+                _ARCHS[path] = _module("arch", name, path)
+            return _ARCHS[path]
+    raise KeyError(f"no architecture module {name}.py in "
+                   f"{[str(d) for d in ARCH_DIRS]}")
+
+
+def config_arch(conf: dict):
+    """The architecture module that a configuration names."""
+    if "arch" not in conf:
+        raise KeyError(f"configuration {conf.get('name')!r} names no "
+                       "architecture: give it \"arch\", the name of a "
+                       "module in arch/")
+    return load_arch(conf["arch"])
 
 
 def cell_metrics(bench: dict, cell: str, section: str) -> list:
@@ -68,9 +101,13 @@ def cell_metrics(bench: dict, cell: str, section: str) -> list:
 
 
 def model_config(conf: dict):
+    """The program's ``ModelConfig`` from the ``model`` block; its
+    ``family`` is the block's own, a dense decoder where it gives
+    none."""
     from repro.config import FAMILY_DECODER, ModelConfig
-    return ModelConfig(name=conf["name"], family=FAMILY_DECODER,
-                       **conf["model"])
+    model = dict(conf["model"])
+    family = model.pop("family", FAMILY_DECODER)
+    return ModelConfig(name=conf["name"], family=family, **model)
 
 
 def engine_config(conf: dict):

@@ -1,11 +1,12 @@
 """Model step: device time per run of the fused decode program, from
-the device trace (the program runs that ran the paged decode kernel)."""
-KERNEL = "_paged_decode_pallas"
+the device trace (the program runs that ran the architecture's decode
+kernel, ``DECODE_KERNEL`` of its module)."""
 
 
 def read(ctx):
     tr = ctx.device_trace()
-    if tr is None:
+    kernel = getattr(ctx.arch, "DECODE_KERNEL", None)
+    if tr is None or kernel is None:
         return None
-    secs, n = tr.sum_programs(KERNEL)
+    secs, n = tr.sum_programs(kernel)
     return 1e3 * secs / n if n else None

@@ -1,6 +1,6 @@
 """Model step: model flops of the traced slice's
 decode steps and prefill chunks over the slice's length times the
-chip's bf16 peak (``flops.step_mfu``)."""
+traced devices times the bf16 peak (``flops.step_mfu``)."""
 import flops
 
 

@@ -3,13 +3,18 @@
     python3 bench/run.py --workload glm4-9b.chat --seed 7 --seconds 51 --trace 0
 
 The cell (``BENCHMARK.json``) names a configuration
-(``configs/<config>.json``: model sizes and engine settings) and a
-traffic mix (``traffic/<mix>.json``, read by ``loadgen.py``); its load
-is in ``cells/<cell>.json``.  One process holds the chip.  It makes the
-weights from ``--seed`` on the device, builds ``ServingEngine`` with the
-configuration's settings behind ``ServingFrontend``, and drives the
-mix's schedule on the host clock: the mix's warm phase first (set-up),
-then a window of ``--seconds``.  Requests are timed from their due time.
+(``configs/<config>.json``: model sizes, engine settings and the
+architecture module in ``arch/`` that makes its weights and reference)
+and a traffic mix (``traffic/<mix>.json``, read by ``loadgen.py``); its
+load is in ``cells/<cell>.json``.  One process holds the chips.  It
+makes the weights from ``--seed`` on the device, builds
+``ServingEngine`` with the configuration's settings behind
+``ServingFrontend``, and drives the mix's schedule on the host clock:
+the mix's warm phase first (set-up), then a window of ``--seconds``.
+Requests are timed from their due time.  A cell whose file names
+``replicas`` runs one engine per chip it asks for, each with the same
+weights, behind ``ReplicaCluster`` with the file's routing and shared
+tier; its counts are summed over the replicas.
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1``
 records a profiler trace over the start of the window (at most
@@ -18,8 +23,9 @@ engine's layers, and prints the per-layer metrics instead.  Each metric
 is read by ``metrics/<name>.py``.
 
 After the window the outputs are checked: a sample of finished
-requests, drawn from the seed and holding the longest, is run through
-the float32 reference (``reference.py``) over prompt and served tokens,
+requests, drawn from the seed and holding the longest and one of each
+replica's, is run through
+the float32 reference (``arch/<arch>.py``) over prompt and served tokens,
 and the widest gap by which a served token's logit lies below the
 reference's best must stay within the cell's limit.
 
@@ -69,16 +75,21 @@ class NoChip(RuntimeError):
 # not changed)
 # ---------------------------------------------------------------------------
 class Instrument:
-    """Counts taken around calls into the engine, and host spans around
-    its layers when tracing.  ``on`` gates the per-call records."""
+    """Counts taken around calls into the engines (one, or each
+    replica's), and host spans around their layers when tracing.  ``on``
+    gates the per-call records."""
 
-    def __init__(self, eng, spans: bool):
-        import jax
-        self.eng = eng
+    def __init__(self, engines, spans: bool):
         self.on = False
         self.decode = []           # (rows, tokens attended) per decode step
         self.prefill = []          # (start, tokens) per prefill chunk
         self.hits = {}             # request id -> [cow, inject] blocks
+        self.replica = {}          # request id -> index of its engine
+        for k, eng in enumerate(engines):
+            self._wrap(k, eng, spans)
+
+    def _wrap(self, k: int, eng, spans: bool):
+        import jax
         kv = eng.kv
         orig_wc, orig_wcp = kv.write_chunk, kv.write_chunk_positions
 
@@ -106,17 +117,17 @@ class Instrument:
         eng._decode_fused = decode_fused
 
         for name in ("_admit", "_run_prefill_chunks"):
-            setattr(eng, name, self._hit_counter(getattr(eng, name)))
+            setattr(eng, name,
+                    self._hit_counter(k, eng, getattr(eng, name)))
 
         if spans:
             for name in SPANNED:
                 fn = getattr(eng, name)
                 setattr(eng, name, self._span(f"engine.{name}", fn, jax))
 
-    def _hit_counter(self, fn):
-        eng = self.eng
-
+    def _hit_counter(self, k: int, eng, fn):
         def wrapped(req, *a):
+            self.replica[req.request_id] = k
             c0 = eng.cow_share_hits + eng.segment_share_hits
             i0 = eng.inject_hits + eng.segment_inject_hits
             out = fn(req, *a)
@@ -139,8 +150,8 @@ COUNTERS = ("cow_share_hits", "inject_hits", "segment_share_hits",
             "steps")
 
 
-def engine_counts(eng) -> dict:
-    return {k: int(getattr(eng, k)) for k in COUNTERS}
+def engine_counts(engines) -> dict:
+    return {k: sum(int(getattr(e, k)) for e in engines) for k in COUNTERS}
 
 
 # ---------------------------------------------------------------------------
@@ -206,20 +217,35 @@ def _warm_programs(eng) -> None:
     """Compile every program the window calls before any traffic: the
     fused decode step with every row inactive, both prefill chunk
     programs, and the pool scatter at the widths of a chunk and of an
-    injected block.  None writes anything but the scratch page."""
+    injected block, on the engine's device.  None writes anything but
+    the scratch page."""
+    import contextlib
+
+    import jax
+    with (contextlib.nullcontext() if eng.device is None
+          else jax.default_device(eng.device)):
+        _warm_on_device(eng)
+
+
+def _warm_on_device(eng) -> None:
     import jax
     import jax.numpy as jnp
     from repro.serving import kvcache
 
     kv = eng.kv
     sa = eng.scheduler.step_arrays([], kv.n_slots)
-    toks, new = eng._fused_decode(
-        eng.params, kv.decode_state([]), jnp.asarray(sa["tokens"]),
-        jnp.asarray(sa["active"]), jax.random.PRNGKey(0),
-        jnp.asarray(sa["temperature"]), jnp.asarray(sa["top_k"]),
-        jnp.asarray(sa["top_p"]))
-    kv.absorb(new)
-    np.asarray(toks)
+    # twice: on a fresh state, then on the state the step handed back,
+    # as a step that reuses it passes it (on a replica pinned to its
+    # device that state is committed there, and compiles apart)
+    state = kv.decode_state([])
+    for _ in range(2):
+        toks, state = eng._fused_decode(
+            eng.params, state, jnp.asarray(sa["tokens"]),
+            jnp.asarray(sa["active"]), jax.random.PRNGKey(0),
+            jnp.asarray(sa["temperature"]), jnp.asarray(sa["top_k"]),
+            jnp.asarray(sa["top_p"]))
+        kv.absorb(state)
+        np.asarray(toks)
     C = eng.ecfg.prefill_chunk_tokens
     z = jnp.zeros((1, C), jnp.int32)
     jax.block_until_ready(eng._prefill_chunk(
@@ -238,9 +264,13 @@ def _warm_programs(eng) -> None:
     jax.block_until_ready(kv.pools)
 
 
-def choose_sample(done, hits: dict, seed: int, n: int) -> list:
+def choose_sample(done, hits: dict, seed: int, n: int,
+                  replica: dict | None = None) -> list:
     """The longest finished request, the ones with the most blocks
-    reused by CoW and by injection, and the rest drawn from the seed."""
+    reused by CoW and by injection, one drawn from the seed for each
+    replica (``replica``: request id -> engine) that has none yet, and
+    the rest drawn from the seed: at least ``n``, and more only where
+    the replicas ask for it."""
     if not done:
         return []
     done = sorted(done, key=lambda h: h.request.request_id)
@@ -251,30 +281,36 @@ def choose_sample(done, hits: dict, seed: int, n: int) -> list:
         if hits.get(best.request.request_id, [0, 0])[k] > 0 and all(
                 best is not p for p in pick):
             pick.append(best)
-    rest = [h for h in done if all(h is not p for p in pick)]
     rng = np.random.default_rng([seed, 17])
+    where = (replica or {}).get
+    for r in sorted({where(h.request.request_id) for h in done} - {None}):
+        if any(where(p.request.request_id) == r for p in pick):
+            continue
+        mine = [h for h in done if where(h.request.request_id) == r]
+        pick.append(mine[int(rng.integers(len(mine)))])
+    rest = [h for h in done if all(h is not p for p in pick)]
     while len(pick) < n and rest:
         pick.append(rest.pop(int(rng.integers(len(rest)))))
-    return pick[:n]
+    return pick
 
 
-def check_outputs(params, d, sample, control: bool = False) -> dict:
+def check_outputs(arch, params, d, sample, control: bool = False) -> dict:
     """Widest gap (in logit spreads) between the reference's best logit
     and the served token's, over every served token of the sample; with
     ``control``, also the gap of the token the fp8 reference puts
-    first."""
-    import reference
+    first.  The reference is the architecture module's."""
+    from archlib import served_gaps
     gaps, cgaps, n_tok = [], [], 0
     for h in sample:
         p, g = list(h.prompt), list(h.tokens)
         seq = p + g[:-1]
         rows = list(range(len(p) - 1, len(seq)))
-        ref = reference.logits(params, d, seq, rows)
-        gaps.append(float(reference.served_gaps(ref, g).max()))
+        ref = arch.logits(params, d, seq, rows)
+        gaps.append(float(served_gaps(ref, g).max()))
         n_tok += len(g)
         if control:
-            low = reference.logits(params, d, seq, rows, quant="fp8")
-            cgaps.append(float(reference.served_gaps(
+            low = arch.logits(params, d, seq, rows, quant="fp8")
+            cgaps.append(float(served_gaps(
                 ref, low.argmax(axis=1)).max()))
     out = {"logit_gap": max(gaps) if gaps else None,
            "tokens": n_tok, "requests": len(sample)}
@@ -296,14 +332,52 @@ def resolve(workload: str) -> dict:
                                               "per_layer")}
 
 
+def build_engines(cfg, ecfg, params, weights, n_chips: int, replicas):
+    """The engine the front end drives, and the engines under it: one
+    ``ServingEngine`` on the default device with ``params``, or, where
+    the cell names ``replicas``, a ``ReplicaCluster`` of ``n_chips`` of
+    them, replica i on device i.  Each replica gets the same weights,
+    ``params`` where they already lie on its device, else made there by
+    ``weights()``: a copy from the first device would pass through it."""
+    import jax
+    from repro.serving import ServingEngine
+    if replicas is None:
+        if n_chips != 1:
+            raise ValueError(f"a cell on {n_chips} chips names its "
+                             "replicas in its cell file")
+        eng = ServingEngine(cfg, ecfg, params=params)
+        return eng, [eng]
+    from repro.serving.cluster import ReplicaCluster
+
+    def replica(dev):
+        if {dev} == jax.tree.leaves(params)[0].devices():
+            return ServingEngine(cfg, ecfg, params=params, device=dev)
+        with jax.default_device(dev):
+            own = weights()
+        return ServingEngine(cfg, ecfg, params=own, device=dev)
+    cluster = ReplicaCluster(
+        n_replicas=n_chips, routing=replicas["routing"],
+        shared_tier=replicas["shared_tier"], engine_factory=replica)
+    return cluster, list(cluster.engines.values())
+
+
+def memory_line(devices, when: str) -> str:
+    """Bytes in use and the peak so far on each device, in GB."""
+    out = []
+    for dv in devices:
+        m = dv.memory_stats() or {}
+        out.append(f"{m.get('bytes_in_use', 0) / 1e9:.2f}/"
+                   f"{m.get('peak_bytes_in_use', 0) / 1e9:.2f}")
+    return f"memory in use/peak (GB) per device, {when}: {' '.join(out)}"
+
+
 def run(args, spec: dict, *, require_tpu: bool = True, peaks=None,
         fault=None, control: bool = False, log=print) -> dict:
     """One run of one cell; returns the result line's object.  ``fault``
-    (tests) breaks the engine before traffic; ``control`` also reads
+    (tests) breaks each engine before traffic; ``control`` also reads
     the fp8 reference's gap."""
     import jax
 
-    import reference
     import tracing
 
     wl, conf, mix, cell = (spec[k] for k in
@@ -326,34 +400,44 @@ def run(args, spec: dict, *, require_tpu: bool = True, peaks=None,
         lambda ev, secs, **kw: compiles.append(time.monotonic())
         if ev == "/jax/core/compile/backend_compile_duration" else None)
 
-    from repro.serving import ServingEngine
     from repro.serving.frontend import ServingFrontend
 
-    d = reference.dims(conf["model"])
+    arch = harness.config_arch(conf)
+    d = arch.dims(conf["model"])
     cfg, ecfg = harness.model_config(conf), harness.engine_config(conf)
-    params = reference.init_weights(d, args.seed)
+    params = arch.init_weights(d, args.seed)
     jax.block_until_ready(params)
-    eng = ServingEngine(cfg, ecfg, params=params)
+    top, engines = build_engines(
+        cfg, ecfg, params, lambda: arch.init_weights(d, args.seed),
+        wl["chips"], cell.get("replicas"))
     if fault is not None:
-        fault(eng)
-    inst = Instrument(eng, spans=bool(args.trace))
-    fe = ServingFrontend(eng)
+        for eng in engines:
+            fault(eng)
+    inst = Instrument(engines, spans=bool(args.trace))
+    fe = ServingFrontend(top)
     load = dict(cell.get("load", {}))
     if args.rate is not None:
         load["session_rate_per_s"] = args.rate
+    n_slots = sum(eng.kv.n_slots for eng in engines)
     sched = loadgen.build(mix, load, args.seed, args.seconds, d["V"],
-                          ecfg.max_len, eng.kv.n_slots)
-    _warm_programs(eng)
+                          ecfg.max_len, n_slots)
+    # the devices whose memory the run fills, in replica order
+    used = list(dict.fromkeys(eng.device or dev for eng in engines))
+    log(memory_line(used, "engines built"))
+    for eng in engines:
+        _warm_programs(eng)
+    log(memory_line(used, "programs warm"))
 
     t_zero = time.monotonic()
     drv = Driver(fe, sched, t_zero)
     drv.run_until(t_zero + sched.warm_s)
 
+    log(memory_line(used, "warm phase over"))
     w0 = time.monotonic()
     w1 = w0 + args.seconds
     setup_s = time.perf_counter() - T0
-    rc0 = eng.recompiles()
-    c0 = engine_counts(eng)
+    rc0 = [eng.recompiles() for eng in engines]
+    c0 = engine_counts(engines)
     inst.on = True
     trace_dir = None
     if args.trace:
@@ -365,26 +449,31 @@ def run(args, spec: dict, *, require_tpu: bool = True, peaks=None,
         win.__enter__()
         drv.run_until(min(w1, w0 + TRACE_S))
         inst.on = False
-        jax.block_until_ready(eng.kv.pools)
+        jax.block_until_ready([eng.kv.pools for eng in engines])
         win.__exit__(None, None, None)
         jax.profiler.stop_trace()
     drv.run_until(w1)
     inst.on = False
-    rc1 = eng.recompiles()
-    c1 = engine_counts(eng)
+    rc1 = [eng.recompiles() for eng in engines]
+    c1 = engine_counts(engines)
     n_compiles = sum(1 for t in compiles if w0 <= t < w1)
 
     due = [(h, t) for h, t in drv.sent if w0 <= t < w1]
 
     def waited():
-        n_done = sum(h.status == "done" for h, _ in drv.sent)
-        return n_done >= CHECK_REQUESTS and all(
-            h.first_token_t is not None or h.status == "shed"
-            for h, _ in due)
+        # enough finished requests, one that can be sampled from each
+        # replica that admitted any, and a first token for every due one
+        fin = [h for h, _ in drv.sent if h.status == "done"]
+        full = {inst.replica.get(h.request.request_id) for h in fin
+                if len(h.tokens) == h.params.max_new_tokens}
+        return (len(fin) >= CHECK_REQUESTS
+                and full >= set(inst.replica.values())
+                and all(h.first_token_t is not None or h.status == "shed"
+                        for h, _ in due))
     drv.run_until(w1 + GRACE_S, stop=waited)
     wait_end = time.monotonic()
-    stats = dev.memory_stats() or {}
-    peak = int(stats.get("peak_bytes_in_use", 0))
+    mem = [d_.memory_stats() or {} for d_ in used]
+    peak_each = [int(m.get("peak_bytes_in_use", 0)) for m in mem]
     handles = [h for h, _ in drv.sent]
     done = [h for h in handles if h.status == "done"
             and len(h.tokens) == h.params.max_new_tokens]
@@ -393,12 +482,13 @@ def run(args, spec: dict, *, require_tpu: bool = True, peaks=None,
         f"first tokens, {len(done)} of {len(handles)} requests finished, "
         f"engine deltas {eng_stats}, backend compiles in window {n_compiles}")
     log(f"recompiles before/after window: {rc0} {rc1}; "
-        f"slots {eng.kv.n_slots}; memory {stats.get('peak_bytes_in_use')} "
-        f"peak of {stats.get('bytes_limit')}")
+        f"{len(engines)} engine(s), slots {n_slots}; memory peak "
+        f"{[m.get('peak_bytes_in_use') for m in mem]} of "
+        f"{[m.get('bytes_limit') for m in mem]}")
     if rc0 != rc1:
         raise RuntimeError(f"recompiled inside the window: {rc0} -> {rc1}")
 
-    bt = eng.manager.block_tokens
+    bt = engines[0].manager.block_tokens
     records = [Rec(due=t, admit=h.admit_t, first=h.first_token_t,
                    token_times=list(h.token_times), prompt_len=len(h.prompt),
                    hit_tokens=bt * (h.request.prefix_hit_blocks
@@ -413,15 +503,17 @@ def run(args, spec: dict, *, require_tpu: bool = True, peaks=None,
     log("ttft median per quarter of the window (s): "
         + " ".join(f"{q[len(q) // 2]:.3f}" if q else "-" for q in quarters))
     ctx = Ctx(cell=wl["name"], d=d, peaks=peaks, setup_s=setup_s, w0=w0,
-              w1=w1, records=records,
+              w1=w1, arch=arch, records=records,
               token_times=[list(h.token_times) for h in handles],
               counts=eng_stats, decode_calls=list(inst.decode),
               prefill_calls=list(inst.prefill))
 
-    # the program's state goes before the reference comes
+    # the program's state, every replica's, goes before the reference
+    # comes; the reference runs on the first device
     fe.stop(drain=False)
-    hits = dict(inst.hits)
-    del fe, eng, inst, drv
+    hits, replica = dict(inst.hits), dict(inst.replica)
+    n_replicas = len(engines)
+    del fe, top, engines, eng, inst, drv
     gc.collect()
 
     if trace_dir is not None:
@@ -442,9 +534,9 @@ def run(args, spec: dict, *, require_tpu: bool = True, peaks=None,
                            "decode_calls": ctx.decode_calls,
                            "prefill_calls": ctx.prefill_calls}, f)
 
-    sample = choose_sample(done, hits, args.seed, CHECK_REQUESTS)
+    sample = choose_sample(done, hits, args.seed, CHECK_REQUESTS, replica)
     t_chk = time.perf_counter()
-    chk = check_outputs(params, d, sample, control=control)
+    chk = check_outputs(arch, params, d, sample, control=control)
     log(f"check: {chk['requests']} requests, {chk['tokens']} served tokens, "
         f"hits (cow, inject) "
         f"{[hits.get(h.request.request_id, [0, 0]) for h in sample]}, "
@@ -452,6 +544,12 @@ def run(args, spec: dict, *, require_tpu: bool = True, peaks=None,
     limits = cell["limits"]
     compared = {"logit_gap": {"value": chk["logit_gap"],
                               "limit": limits["logit_gap"]}}
+    if n_replicas > 1:
+        # a replica that served requests and has none in the sample is
+        # not vouched for
+        missed = set(replica.values()) - {
+            replica.get(h.request.request_id) for h in sample}
+        compared["replicas_unchecked"] = {"value": len(missed), "limit": 0}
     correct = all(c["value"] is not None and c["value"] <= c["limit"]
                   for c in compared.values())
 
@@ -462,7 +560,8 @@ def run(args, spec: dict, *, require_tpu: bool = True, peaks=None,
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devices), "memory_peak_bytes": peak}
+              "count": len(devices), "memory_peak_bytes": max(peak_each),
+              "memory_peak_bytes_each": peak_each}
     out = {"correct": correct, "attempted": len(records),
            "failed": sum(r.first is None for r in records),
            "metrics": metrics, "device": device}

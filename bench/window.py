@@ -1,6 +1,7 @@
 """What the metric readers see: the requests of the measured window,
-as the client saw them, the counts taken around calls into the engine,
-and the reduced trace of a traced run.
+as the client saw them, the counts taken around calls into the engine
+(summed over replicas), the configuration's architecture module, and
+the reduced trace of a traced run.
 
 Every reader in ``metrics/`` is ``read(ctx) -> float | None``; None
 means it found nothing to read, and the metric is left out of the
@@ -48,11 +49,12 @@ class Rec:
 @dataclass
 class Ctx:
     cell: str
-    d: dict                        # model sizes (reference.dims)
+    d: dict                        # model sizes (arch.dims)
     peaks: dict
     setup_s: float
     w0: float                      # window, host clock
     w1: float
+    arch: Optional[object] = None  # the configuration's arch/ module
     records: List[Rec] = field(default_factory=list)
     token_times: List[List[float]] = field(default_factory=list)
     counts: Dict[str, int] = field(default_factory=dict)   # window deltas
