@@ -853,14 +853,17 @@ def kernel_benchmarks(backend: str = None, fast: bool = False) -> None:
                       lambda q=q, kp=kp, vp=vp, bt=bt, ln=ln:
                       ops.paged_decode_ref(q, kp, vp, bt, ln)))
     dl, dr = 64, 16
+    msc = (hd + dr) ** -0.5          # the query's nope width is hd
     ql, qr = arr((b, 8, dl)), arr((b, 8, dr))
     lat = arr((n, page, dl + dr))
     bt = table(b, pages, n)
     ln = jnp.asarray(rng.integers(page, pages * page, size=b), jnp.int32)
     cases.append(("decode_mla",
                   lambda be: ops.mla_decode(ql, qr, lat, bt, ln,
-                                            d_latent=dl, backend=be),
-                  lambda: ops.mla_decode_ref(ql, qr, lat, bt, ln, dl)))
+                                            d_latent=dl, scale=msc,
+                                            backend=be),
+                  lambda: ops.mla_decode_ref(ql, qr, lat, bt, ln, dl,
+                                             scale=msc)))
     # prefill: a 64-token chunk over 3 resident pages
     c, ppages = 64, 3
     np_ = 2 * ppages + 2
@@ -878,9 +881,10 @@ def kernel_benchmarks(backend: str = None, fast: bool = False) -> None:
     mbt = table(2, ppages, np_)
     cases.append(("prefill_mla",
                   lambda be: ops.mla_prefill(mql, mqr, mlc, mlp, mbt, off,
-                                             d_latent=dl, backend=be),
+                                             d_latent=dl, scale=msc,
+                                             backend=be),
                   lambda: ops.mla_prefill_ref(mql, mqr, mlc, mlp, mbt,
-                                              off, dl)))
+                                              off, dl, scale=msc)))
 
     for name, run, oracle in cases:
         err = float(jnp.max(jnp.abs(run("xla") - oracle())))

@@ -53,11 +53,25 @@ class ModelConfig:
     # --- MLA (DeepSeek-style latent attention) --------------------------
     d_latent: int = 0
     d_rope: int = 0
+    latent_norm: bool = False         # RMSNorm on c_kv before it is cached
+    #                                   (DeepSeek-V2 kv_a_layernorm)
+    # --- YaRN RoPE scaling (DeepSeek-V2 rope_scaling) ----------------------
+    yarn_factor: float = 0.0          # 0: plain RoPE
+    yarn_original_max: int = 4096     # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 0.0  # scales the softmax; no field for
+    #                                   YaRN's ``mscale``: its cos/sin factor
+    #                                   mscale/mscale_all_dim is taken as 1
     # --- MoE -------------------------------------------------------------
     n_experts: int = 0
     top_k: int = 0
     expert_d_ff: int = 0              # per-expert hidden dim (granite: 512)
-    capacity_factor: float = 1.25
+    n_shared_experts: int = 0         # always-on experts of expert_d_ff,
+    #                                   run as one SwiGLU of n x that width
+    n_dense_layers: int = 0           # leading layers with a dense FFN of
+    #                                   d_ff (DeepSeek first_k_dense_replace)
+    norm_topk_prob: bool = True       # renormalise the top-k gate weights
     router_aux_weight: float = 0.01
     # --- SSM / Mamba2 (zamba2) -------------------------------------------
     ssm_state: int = 0
@@ -134,6 +148,21 @@ class ModelConfig:
     def moe_ff(self) -> int:
         return self.expert_d_ff or self.d_ff
 
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.n_experts else 0
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        """Softmax scale of MLA attention over its (hd + d_rope)-wide
+        query: DeepSeek-V2 multiplies ``q_head_dim ** -0.5`` by YaRN's
+        ``mscale_all_dim`` factor squared."""
+        scale = 1.0 / math.sqrt(self.hd + self.d_rope)
+        if self.yarn_factor > 1 and self.yarn_mscale_all_dim:
+            scale *= yarn_mscale(self.yarn_factor,
+                                 self.yarn_mscale_all_dim) ** 2
+        return scale
+
     def attn_layer_ids(self) -> Tuple[int, ...]:
         """For hybrid models: indices of SSM layers after which the shared
         attention block runs.  Zamba2 interleaves a shared attention block
@@ -168,7 +197,8 @@ class ModelConfig:
 
         def ffn_params() -> int:
             if self.n_experts > 0:
-                return (self.n_experts * 3 * d * self.moe_ff) + d * self.n_experts
+                return ((self.n_experts + self.n_shared_experts) * 3 * d
+                        * self.moe_ff) + d * self.n_experts
             return 3 * d * self.d_ff
 
         def ssm_params() -> int:
@@ -191,6 +221,10 @@ class ModelConfig:
         if self.family in (FAMILY_DECODER, FAMILY_MOE, FAMILY_VLM):
             per_layer = attn_params() + ffn_params() + 2 * d
             total += self.n_layers * per_layer
+            if self.n_experts and self.n_dense_layers:
+                # leading layers hold a dense SwiGLU instead of experts
+                total += self.n_dense_layers * (3 * d * self.d_ff
+                                                - ffn_params())
             if self.family == FAMILY_VLM:
                 total += len(self.cross_attn_layer_ids()) * (attn_params() + 2 * d)
         elif self.family == FAMILY_HYBRID:
@@ -209,8 +243,17 @@ class ModelConfig:
         if self.n_experts == 0:
             return self.param_count()
         dense_expert = 3 * self.d_model * self.moe_ff
-        inactive = self.n_layers * (self.n_experts - self.top_k) * dense_expert
+        inactive = (self.n_moe_layers * (self.n_experts - self.top_k)
+                    * dense_expert)
         return self.param_count() - inactive
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2
+    ``yarn_get_mscale``)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
 
 
 # ---------------------------------------------------------------------------

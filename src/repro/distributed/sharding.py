@@ -50,7 +50,6 @@ BASE_RULES: Rules = {
     "inner": "model",
     "experts": None,
     "kv_seq": "model",
-    "moe_groups": ("pod", "data"),
     "seq": None,
     "layers": None,
     "embed": None,
@@ -64,9 +63,8 @@ BASE_RULES["seq_res"] = None      # residual-stream seq dim (SP when set)
 LONG_CONTEXT_RULES: Rules = dict(BASE_RULES, kv_seq=("data", "model"))
 
 # Expert parallelism: experts sharded over the model axis (per-expert
-# hidden dim whole per shard).  MoE fwd/bwd cross-shard reductions then
-# move token-space [G,t,d] tensors instead of slot-space [G,E,C,d]
-# (top_k * capacity_factor ~= 10x smaller for granite).
+# hidden dim whole per shard), so the cross-shard reductions of the MoE
+# layer move token-space tensors rather than per-expert ones.
 EP_RULES: Rules = dict(BASE_RULES, experts="model", mlp=None)
 
 # Sequence parallelism: the residual stream between blocks is sharded on
@@ -212,6 +210,44 @@ class MeshSharding:
             lookup, mesh=self.mesh,
             in_specs=(P(v_axis, None), tok_ps),
             out_specs=out_ps, check_vma=False)(emb, tokens)
+
+
+    # -- routed experts, each shard on its own ----------------------------
+    def expert_map(self, fn, xt, top_i, top_p, valid, w_gate, w_up,
+                   w_down, layer):
+        """``fn`` (``moe.routed_sum``) under shard_map: each shard takes
+        its batch rows of the tokens and routing, and the experts
+        (``experts`` rule) and hidden slice (``mlp`` rule) it holds of
+        the stacked weights [Lm, E, ...]; the partial sums add up over
+        the axes that split experts or hidden.  XLA cannot partition
+        the grouped-matmul kernel, and would otherwise gather every
+        expert's weights onto every device."""
+        if self.mesh.empty or np.prod(self.mesh.devices.shape) == 1:
+            return fn(xt, top_i, top_p, valid, w_gate, w_up, w_down, layer)
+        mesh = self.mesh
+        tok = _evenly_shardable(P(self.rules.get("batch")), xt.shape[:1],
+                                mesh)
+        b_ax = tuple(tok)[0] if tuple(tok) else None
+        up = _evenly_shardable(P(None, self.rules.get("experts"), None,
+                                 self.rules.get("mlp")), w_gate.shape, mesh)
+        up = tuple(up) + (None,) * (4 - len(tuple(up)))
+        e_ax, f_ax = up[1], up[3]
+        down = P(None, e_ax, f_ax, None)
+        summed = tuple(a for ax in (e_ax, f_ax) if ax is not None
+                       for a in (ax if isinstance(ax, tuple) else (ax,)))
+
+        def body(xt, top_i, top_p, valid, wg, wu, wd, layer):
+            e0 = (jax.lax.axis_index(e_ax) * wg.shape[1]
+                  if e_ax is not None else 0)
+            out = fn(xt, top_i, top_p, valid, wg, wu, wd, layer, e0)
+            return jax.lax.psum(out, summed) if summed else out
+
+        rows = P(b_ax, None)
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(rows, rows, rows, P(b_ax), P(*up), P(*up), down, P()),
+            out_specs=rows, check_vma=False)(
+                xt, top_i, top_p, valid, w_gate, w_up, w_down, layer)
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,6 @@ table resolved by scalar prefetch.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -66,24 +65,19 @@ def _mla_kernel(block_tables_ref, lengths_ref, q_lat_ref, q_rope_ref,
 
 def mla_paged_decode(q_lat: jax.Array, q_rope: jax.Array,
                      latent_pages: jax.Array, block_tables: jax.Array,
-                     lengths: jax.Array, *, d_latent: int,
-                     head_dim: int = 128, scale: float = None,
+                     lengths: jax.Array, *, d_latent: int, scale: float,
                      interpret: bool | None = None) -> jax.Array:
     """q_lat [B,Hq,dl], q_rope [B,Hq,dr]; latent_pages [N,page,dl+dr];
     -> ctx [B,Hq,dl] (caller applies W_uv + output projection).
 
-    ``scale`` overrides the softmax scale; the default keeps the
-    dl//4 + dr convention of the reference oracle (hd ~ dl/4).  The
-    live engine passes 1/sqrt(hd + dr) to match the absorbed-form
-    dense decode exactly.
+    ``scale`` is the softmax scale, the model's own (its query width
+    and any YaRN factor are the caller's to know).
     """
     interpret = resolve_interpret(interpret)
     b, hq, dl = q_lat.shape
     dr = q_rope.shape[-1]
     n, page, dtot = latent_pages.shape
     p_max = block_tables.shape[1]
-    if scale is None:
-        scale = 1.0 / math.sqrt(dl // 4 + dr)  # matches ref convention
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -18,6 +18,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref, xla_fallback
 from repro.kernels.backend import (BACKENDS, default_backend,  # noqa: F401
@@ -50,7 +51,7 @@ def _flash_causal_pallas(q, k, v, block_q: int, block_k: int,
 @functools.partial(jax.jit, static_argnames=("d_latent", "scale",
                                              "interpret"))
 def _mla_decode_pallas(q_lat, q_rope, latent_pages, block_tables, lengths,
-                       d_latent: int, scale: float | None, interpret: bool):
+                       d_latent: int, scale: float, interpret: bool):
     return mla_paged_decode(q_lat, q_rope, latent_pages, block_tables,
                             lengths, d_latent=d_latent, scale=scale,
                             interpret=interpret)
@@ -68,7 +69,7 @@ def _paged_prefill_pallas(q, k_chunk, v_chunk, k_pages, v_pages,
                                              "interpret"))
 def _mla_prefill_pallas(q_lat, q_rope, lat_chunk, latent_pages,
                         block_tables, offsets, d_latent: int,
-                        scale: float | None, interpret: bool):
+                        scale: float, interpret: bool):
     return mla_paged_prefill(q_lat, q_rope, lat_chunk, latent_pages,
                              block_tables, offsets, d_latent=d_latent,
                              scale=scale, interpret=interpret)
@@ -87,7 +88,7 @@ def _paged_prefill_seg_pallas(q, k_chunk, v_chunk, k_pages, v_pages,
                                              "interpret"))
 def _mla_prefill_seg_pallas(q_lat, q_rope, lat_chunk, latent_pages,
                             block_tables, chunk_positions, d_latent: int,
-                            scale: float | None, interpret: bool):
+                            scale: float, interpret: bool):
     return mla_paged_prefill_segments(q_lat, q_rope, lat_chunk,
                                       latent_pages, block_tables,
                                       chunk_positions, d_latent=d_latent,
@@ -103,7 +104,61 @@ def _paged_decode_int8_pallas(q, k_pages, v_pages, k_scales, v_scales,
                                        interpret=interpret)
 
 
+GMM_TM = 32
+
+
+def _gmm_tiling(k: int, n: int) -> tuple:
+    """(tm, tk, tn) of the expert grouped matmul: 32-row tiles, since a
+    decode step or a 64-token chunk gives each expert only a few rows;
+    a 512-deep contraction tile where it divides ``k``; and the widest
+    output tile (a multiple of 128 that divides ``n``, else all of
+    ``n``) that keeps one weight tile within 2 MiB of bf16."""
+    tk = 512 if k > 512 and k % 512 == 0 else k
+    fits = [t for t in range(128, n + 1, 128)
+            if n % t == 0 and tk * t * 2 <= 2 << 20]
+    return GMM_TM, tk, (max(fits) if fits else n)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _expert_gmm_pallas(lhs, rhs, group_sizes, layer, interpret: bool):
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    lm, e, k, n = rhs.shape
+    m = lhs.shape[0]
+    m_pad = -(-m // GMM_TM) * GMM_TM
+    if m_pad != m:
+        lhs = jnp.pad(lhs, ((0, m_pad - m), (0, 0)))
+    # every MoE layer's experts as one [Lm * E] group list, the groups
+    # of the other layers empty: the kernel reads layer ``layer``'s
+    # weights in place (a slice of the stack would be copied first)
+    sizes = jnp.zeros((lm, e), jnp.int32).at[layer].set(
+        group_sizes.astype(jnp.int32)).reshape(lm * e)
+    # (the custom-VJP megablox gmm, so training differentiates it)
+    out = gmm(lhs, rhs.reshape(lm * e, k, n), sizes, lhs.dtype,
+              _gmm_tiling(k, n), None, None, False, interpret)
+    return out[:m]
+
+
+@jax.jit
+def _expert_gmm_xla(lhs, rhs, group_sizes, layer):
+    w = jax.lax.dynamic_index_in_dim(rhs, layer, keepdims=False)
+    return jax.lax.ragged_dot(lhs, w, group_sizes.astype(jnp.int32))
+
+
 # -- dispatching public ops -------------------------------------------------
+def expert_matmul(lhs, rhs, group_sizes, layer, backend: str | None = None,
+                  interpret: bool | None = None):
+    """Grouped matmul of MoE layer ``layer``: lhs [m, K] rows sorted by
+    expert, ``group_sizes`` [E] rows per expert (rows past their sum
+    are left undefined), rhs the stacked experts [Lm, E, K, N] ->
+    [m, N] in lhs's dtype."""
+    be = resolve_backend(backend, interpret)
+    if be == "xla":
+        return _expert_gmm_xla(lhs, rhs, group_sizes, layer)
+    return _expert_gmm_pallas(lhs, rhs, group_sizes, layer,
+                              interpret=(be == "interpret"))
+
+
+
 def paged_decode(q, k_pages, v_pages, block_tables, lengths,
                  backend: str | None = None, interpret: bool | None = None):
     """Paged decode attention (GQA/MHA/MQA): q [B,Hq,hd] over block-table
@@ -127,7 +182,7 @@ def flash_causal(q, k, v, block_q: int = 128, block_k: int = 128,
 
 
 def mla_decode(q_lat, q_rope, latent_pages, block_tables, lengths,
-               d_latent: int, scale: float | None = None,
+               d_latent: int, scale: float,
                backend: str | None = None, interpret: bool | None = None):
     """Absorbed-MLA paged decode over latent pages -> ctx [B,Hq,dl]."""
     be = resolve_backend(backend, interpret)
@@ -155,7 +210,7 @@ def paged_prefill(q, k_chunk, v_chunk, k_pages, v_pages, block_tables,
 
 
 def mla_prefill(q_lat, q_rope, lat_chunk, latent_pages, block_tables,
-                offsets, d_latent: int, scale: float | None = None,
+                offsets, d_latent: int, scale: float,
                 backend: str | None = None, interpret: bool | None = None):
     """Absorbed-MLA chunked prefill over latent pages."""
     be = resolve_backend(backend, interpret)
@@ -188,8 +243,7 @@ def paged_prefill_seg(q, k_chunk, v_chunk, k_pages, v_pages, block_tables,
 
 
 def mla_prefill_seg(q_lat, q_rope, lat_chunk, latent_pages, block_tables,
-                    chunk_positions, d_latent: int,
-                    scale: float | None = None,
+                    chunk_positions, d_latent: int, scale: float,
                     backend: str | None = None,
                     interpret: bool | None = None):
     """Absorbed-MLA segment prefill over latent pages (same position

@@ -340,7 +340,7 @@ def mla_paged_prefill_segments(q_lat: jax.Array, q_rope: jax.Array,
                                latent_pages: jax.Array,
                                block_tables: jax.Array,
                                chunk_positions: jax.Array, *,
-                               d_latent: int, scale: float = None,
+                               d_latent: int, scale: float,
                                interpret: bool | None = None) -> jax.Array:
     """Absorbed-MLA segment prefill (same position semantics as
     ``paged_prefill_segments``): q_lat [B,C,Hq,dl]; q_rope [B,C,Hq,dr];
@@ -352,8 +352,6 @@ def mla_paged_prefill_segments(q_lat: jax.Array, q_rope: jax.Array,
     dr = q_rope.shape[-1]
     n, page, dtot = latent_pages.shape
     p_max = block_tables.shape[1]
-    if scale is None:
-        scale = 1.0 / math.sqrt(dl // 4 + dr)  # ref-oracle convention
     th = _mla_head_tile(c, hq, dl, dr, page, q_lat.dtype.itemsize)
     limits = _pool_limits(chunk_positions, c)
     cp3 = chunk_positions.astype(jnp.int32)[:, :, None]   # [B, C, 1]
@@ -395,7 +393,7 @@ def mla_paged_prefill_segments(q_lat: jax.Array, q_rope: jax.Array,
 def mla_paged_prefill(q_lat: jax.Array, q_rope: jax.Array,
                       lat_chunk: jax.Array, latent_pages: jax.Array,
                       block_tables: jax.Array, offsets: jax.Array, *,
-                      d_latent: int, scale: float = None,
+                      d_latent: int, scale: float,
                       interpret: bool | None = None) -> jax.Array:
     """q_lat [B,C,Hq,dl]; q_rope [B,C,Hq,dr]; lat_chunk [B,C,dl+dr];
     latent_pages [N,page,dl+dr]; offsets [B] -> ctx [B,C,Hq,dl].  The

@@ -35,15 +35,13 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables,
 
 def mla_paged_decode_ref(q_lat, q_rope, latent_pages, block_tables,
                          lengths, d_latent: int,
-                         scale: float = None) -> jax.Array:
+                         scale: float) -> jax.Array:
     """q_lat [B,Hq,dl]; q_rope [B,Hq,dr]; latent_pages [N,page,dl+dr];
     -> ctx [B,Hq,dl] (absorbed-form attention output in latent space)."""
     b, hq, dl = q_lat.shape
     dr = q_rope.shape[-1]
     n, page, dtot = latent_pages.shape
     p_max = block_tables.shape[1]
-    if scale is None:
-        scale = 1.0 / math.sqrt(dl // 4 + dr)  # hd ~ dl/4 convention of caller
 
     def one(ql, qr, bt, ln):
         lat = latent_pages[bt].reshape(p_max * page, dtot)
@@ -138,14 +136,12 @@ def paged_prefill_segments_ref(q, k_chunk, v_chunk, k_pages, v_pages,
 
 def mla_paged_prefill_ref(q_lat, q_rope, lat_chunk, latent_pages,
                           block_tables, offsets, d_latent: int,
-                          scale: float = None) -> jax.Array:
+                          scale: float) -> jax.Array:
     """Absorbed-MLA chunked-prefill oracle -> ctx [B,C,Hq,dl]."""
     b, c, hq, dl = q_lat.shape
     dr = q_rope.shape[-1]
     n, page, dtot = latent_pages.shape
     p_max = block_tables.shape[1]
-    if scale is None:
-        scale = 1.0 / math.sqrt(dl // 4 + dr)
 
     def one(ql, qr, lc, bt, off):
         lat = jnp.concatenate(
@@ -170,15 +166,13 @@ def mla_paged_prefill_ref(q_lat, q_rope, lat_chunk, latent_pages,
 def mla_paged_prefill_segments_ref(q_lat, q_rope, lat_chunk, latent_pages,
                                    block_tables, chunk_positions,
                                    d_latent: int,
-                                   scale: float = None) -> jax.Array:
+                                   scale: float) -> jax.Array:
     """Absorbed-MLA segment-prefill oracle (same position semantics as
     ``paged_prefill_segments_ref``) -> ctx [B,C,Hq,dl]."""
     b, c, hq, dl = q_lat.shape
     dr = q_rope.shape[-1]
     n, page, dtot = latent_pages.shape
     p_max = block_tables.shape[1]
-    if scale is None:
-        scale = 1.0 / math.sqrt(dl // 4 + dr)
 
     def one(ql, qr, lc, bt, cpos):
         lat = jnp.concatenate(

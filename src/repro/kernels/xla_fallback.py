@@ -89,15 +89,13 @@ def paged_decode_attention_int8_xla(q, k_pages, v_pages, k_scales,
 def mla_paged_decode_xla(q_lat: jax.Array, q_rope: jax.Array,
                          latent_pages: jax.Array, block_tables: jax.Array,
                          lengths: jax.Array, *, d_latent: int,
-                         scale: float | None = None) -> jax.Array:
+                         scale: float) -> jax.Array:
     """Absorbed-MLA decode over latent pages: q_lat [B,Hq,dl];
     q_rope [B,Hq,dr]; latent_pages [N,page,dl+dr] -> ctx [B,Hq,dl]."""
     b, hq, dl = q_lat.shape
     dr = q_rope.shape[-1]
     _, page, dtot = latent_pages.shape
     t = block_tables.shape[1] * page
-    if scale is None:
-        scale = 1.0 / math.sqrt(dl // 4 + dr)  # ref-oracle convention
 
     lat = jnp.take(latent_pages, block_tables, axis=0, mode="clip").reshape(b, t, dtot)
     lat = lat.astype(jnp.float32)
@@ -189,15 +187,13 @@ def mla_paged_prefill_segments_xla(q_lat: jax.Array, q_rope: jax.Array,
                                    block_tables: jax.Array,
                                    chunk_positions: jax.Array, *,
                                    d_latent: int,
-                                   scale: float | None = None) -> jax.Array:
+                                   scale: float) -> jax.Array:
     """Absorbed-MLA segment prefill (same position semantics as
     ``paged_prefill_segments_xla``) -> ctx [B,C,Hq,dl]."""
     b, c, hq, dl = q_lat.shape
     dr = q_rope.shape[-1]
     _, page, dtot = latent_pages.shape
     t_prior = block_tables.shape[1] * page
-    if scale is None:
-        scale = 1.0 / math.sqrt(dl // 4 + dr)  # ref-oracle convention
 
     lat_p = jnp.take(latent_pages, block_tables,
                      axis=0, mode="clip").reshape(b, t_prior, dtot)
@@ -222,7 +218,7 @@ def mla_paged_prefill_xla(q_lat: jax.Array, q_rope: jax.Array,
                           lat_chunk: jax.Array, latent_pages: jax.Array,
                           block_tables: jax.Array, offsets: jax.Array, *,
                           d_latent: int,
-                          scale: float | None = None) -> jax.Array:
+                          scale: float) -> jax.Array:
     """Absorbed-MLA chunked prefill: q_lat [B,C,Hq,dl]; q_rope
     [B,C,Hq,dr]; lat_chunk [B,C,dl+dr]; latent_pages [N,page,dl+dr]
     -> ctx [B,C,Hq,dl]."""
@@ -230,8 +226,6 @@ def mla_paged_prefill_xla(q_lat: jax.Array, q_rope: jax.Array,
     dr = q_rope.shape[-1]
     _, page, dtot = latent_pages.shape
     t_prior = block_tables.shape[1] * page
-    if scale is None:
-        scale = 1.0 / math.sqrt(dl // 4 + dr)  # ref-oracle convention
 
     lat_p = jnp.take(latent_pages, block_tables,
                      axis=0, mode="clip").reshape(b, t_prior, dtot)

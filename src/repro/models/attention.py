@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
-from repro.models.common import NOSHARD, PSpec, apply_rope
+from repro.models.common import (NOSHARD, PSpec, apply_rope, rms_norm,
+                                 yarn_freqs)
 
 NEG_INF = -1e30
 SCORES_BF16 = False   # set True to store score buffers at bf16 (perf flag)
@@ -66,7 +67,7 @@ def attn_pspecs(cfg: ModelConfig, *, cross: bool = False) -> Dict[str, PSpec]:
 def mla_pspecs(cfg: ModelConfig) -> Dict[str, PSpec]:
     d, hq, hd = cfg.d_model, cfg.n_heads, cfg.hd
     dl, dr = cfg.d_latent, cfg.d_rope
-    return {
+    out = {
         "wq": PSpec((d, hq, hd + dr), ("embed", "heads", None)),
         "w_dkv": PSpec((d, dl), ("embed", "latent")),
         "w_kr": PSpec((d, dr), ("embed", None)),
@@ -75,6 +76,9 @@ def mla_pspecs(cfg: ModelConfig) -> Dict[str, PSpec]:
         "wo": PSpec((hq, hd, d), ("heads", None, "embed"),
                     scale=0.02 / math.sqrt(2 * max(cfg.n_layers, 1))),
     }
+    if cfg.latent_norm:
+        out["kv_norm"] = PSpec((dl,), ("latent",), init="ones")
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -120,6 +124,7 @@ def _grouped_out(probs: jax.Array, v: jax.Array) -> jax.Array:
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      *, q_offset: int = 0, chunk: int = 512,
+                     scale: Optional[float] = None,
                      shd=NOSHARD) -> jax.Array:
     """Exact causal attention with bounded memory: scan over query chunks,
     each chunk softmaxes over the full (masked) key range.
@@ -139,7 +144,8 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         chunk = sq          # irregular smoke shapes: single chunk
     nc = sq // chunk
     kpos = jnp.arange(sk)
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     def one_chunk(ci, qc):
         # qc [B, chunk, Hq, hd]
@@ -186,7 +192,8 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qs = q.reshape(b, nc, chunk, hq, hd).transpose(1, 0, 2, 3, 4)
     outs = jax.lax.map(lambda args: one_chunk(args[0], args[1]),
                        (jnp.arange(nc), qs))
-    return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, hq, hd)
+    # v's head width, which MLA's (hd + d_rope)-wide queries exceed
+    return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, hq, v.shape[-1])
 
 
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -240,17 +247,32 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 # ---------------------------------------------------------------------------
 # MLA — latent attention (paper §II-B): cache = [c_kv ; k_rope]
 # ---------------------------------------------------------------------------
+def mla_rope(x: jax.Array, positions: jax.Array,
+             cfg: ModelConfig) -> jax.Array:
+    """RoPE on the rope part of MLA queries and keys [B,S,H,dr], with
+    YaRN's frequencies where the config scales."""
+    freqs = None
+    if cfg.yarn_factor > 1:
+        freqs = jnp.asarray(yarn_freqs(
+            cfg.d_rope, cfg.rope_theta, cfg.yarn_factor,
+            cfg.yarn_original_max, cfg.yarn_beta_fast, cfg.yarn_beta_slow))
+    return apply_rope(x, positions, cfg.rope_theta, freqs=freqs)
+
+
 def mla_project(p: Dict, x: jax.Array, positions: jax.Array,
                 cfg: ModelConfig, shd=NOSHARD):
-    """Returns q_nope [B,S,H,hd], q_rope [B,S,H,dr], latent [B,S,dl+dr]."""
+    """Returns q_nope [B,S,H,hd], q_rope [B,S,H,dr], latent [B,S,dl+dr].
+    The cached latent is ``[c_kv ; k_rope]``, ``c_kv`` normalised first
+    where the config has a latent norm."""
     dr = cfg.d_rope
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :-dr], q[..., -dr:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = mla_rope(q_rope, positions, cfg)
     c_kv = jnp.einsum("bsd,dl->bsl", x, p["w_dkv"])
+    if cfg.latent_norm:
+        c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
     k_rope = jnp.einsum("bsd,dr->bsr", x, p["w_kr"])
-    k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        cfg.rope_theta)[:, :, 0, :]
+    k_rope = mla_rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0, :]
     latent = jnp.concatenate([c_kv, k_rope], axis=-1)   # the cached state
     return q_nope, q_rope, shd(latent, "batch", "seq", None)
 
@@ -269,7 +291,8 @@ def mla_attention_prefill(p: Dict, x: jax.Array, positions: jax.Array,
     k = jnp.concatenate(
         [k, jnp.broadcast_to(k_rope[:, :, None, :], k.shape[:3] + (dr,))],
         axis=-1)
-    out = causal_attention(q, k, v, chunk=chunk, shd=shd)
+    out = causal_attention(q, k, v, chunk=chunk,
+                           scale=cfg.mla_softmax_scale, shd=shd)
     o = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return o, latent
 
@@ -294,7 +317,7 @@ def mla_attention_decode(p: Dict, x: jax.Array, latent_cache: jax.Array,
     q_lat = jnp.einsum("bshk,lhk->bshl", q_nope, p["w_uk"])
     s = (jnp.einsum("bshl,btl->bhst", q_lat, c_kv)
          + jnp.einsum("bshr,btr->bhst", q_rope, k_rope)).astype(jnp.float32)
-    s = s / math.sqrt(cfg.hd + dr)
+    s = s * cfg.mla_softmax_scale
     valid = jnp.arange(latent_cache.shape[1])[None, :] < lengths[:, None]
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     pr = jax.nn.softmax(s, axis=-1).astype(x.dtype)

@@ -84,6 +84,11 @@ class NoSharding:
     def embed_lookup(self, emb, tokens):
         return emb[tokens]
 
+    def expert_map(self, fn, xt, top_i, top_p, valid, w_gate, w_up,
+                   w_down, layer):
+        """``fn`` (``moe.routed_sum``) over every expert, here."""
+        return fn(xt, top_i, top_p, valid, w_gate, w_up, w_down, layer)
+
     def dp_size(self) -> int:
         return 1
 
@@ -133,10 +138,35 @@ def rope_freqs(dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x [..., seq, heads, head_dim]; positions broadcastable to [..., seq]."""
+def yarn_freqs(dim: int, theta: float, factor: float, original_max: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies [dim/2] (DeepSeek-V2
+    ``DeepseekV2YarnRotaryEmbedding``): the fast-rotating dimensions keep
+    their frequency, the slow ones are divided by ``factor``, with a
+    linear ramp between the correction dimensions of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max`` positions."""
+    def corr_dim(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    inter = extra / factor
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp                     # 1 where the frequency is kept
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs=None) -> jax.Array:
+    """x [..., seq, heads, head_dim]; positions broadcastable to [..., seq].
+    ``freqs`` [head_dim/2] replaces the plain inverse frequencies (YaRN)."""
     dim = x.shape[-1]
-    freqs = rope_freqs(dim, theta)                       # [dim/2]
+    if freqs is None:
+        freqs = rope_freqs(dim, theta)                   # [dim/2]
     angles = positions[..., None].astype(jnp.float32) * freqs   # [..., s, dim/2]
     cos = jnp.cos(angles)[..., None, :]                  # [..., s, 1, dim/2]
     sin = jnp.sin(angles)[..., None, :]

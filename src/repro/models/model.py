@@ -206,10 +206,11 @@ class DecoderModel(Model):
             a = attn.mla_pspecs(cfg)
         else:
             a = attn.attn_pspecs(cfg)
-        ffn = (moe_mod.moe_pspecs(cfg) if cfg.n_experts > 0
-               else _dense_ffn_pspecs(cfg))
-        return {"attn": a, "ffn": ffn,
-                "ln1": _ln_spec(cfg.d_model), "ln2": _ln_spec(cfg.d_model)}
+        out = {"attn": a,
+               "ln1": _ln_spec(cfg.d_model), "ln2": _ln_spec(cfg.d_model)}
+        if cfg.n_experts == 0:
+            out["ffn"] = _dense_ffn_pspecs(cfg)
+        return out
 
     def param_specs(self) -> Params:
         cfg = self.cfg
@@ -218,6 +219,14 @@ class DecoderModel(Model):
             "layers": stack_specs(self._layer_specs(), cfg.n_layers),
             "ln_f": _ln_spec(cfg.d_model),
         }
+        if cfg.n_experts > 0:
+            # FFNs of a MoE model stack apart from the layers: the
+            # leading dense ones, then the MoE ones (see ``_ffn``)
+            out["moe"] = stack_specs(moe_mod.moe_pspecs(cfg),
+                                     cfg.n_moe_layers)
+            if cfg.n_dense_layers:
+                out["dense"] = stack_specs(_dense_ffn_pspecs(cfg),
+                                           cfg.n_dense_layers)
         if not cfg.tie_embeddings:
             out["head"] = PSpec((cfg.d_model, cfg.vocab_size),
                                 ("embed", "vocab"))
@@ -232,15 +241,46 @@ class DecoderModel(Model):
         return out
 
     # -- blocks -----------------------------------------------------------
-    def _ffn(self, lp, h):
+    def _ffn(self, params, lp, i, h, valid=None, backend=None):
+        """The FFN of layer ``i`` (a traced scan index) -> (out, aux
+        loss, tokens per expert [E] or None).  A MoE model's first
+        ``n_dense_layers`` layers run a dense SwiGLU and the rest MoE
+        layer ``i - n_dense_layers``, chosen on the device, so one scan
+        covers every layer and the KV pool is never split by layer.
+        ``valid`` masks rows out of the experts and their counts."""
         cfg = self.cfg
-        if cfg.n_experts > 0:
-            out, aux = moe_mod.moe_ffn(lp["ffn"], h, cfg, self.shd)
-            return out, aux
-        return swiglu(h, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
-                      lp["ffn"]["w_down"], self.shd), 0.0
+        if cfg.n_experts == 0:
+            return swiglu(h, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
+                          lp["ffn"]["w_down"], self.shd), 0.0, None
+        n_dense = cfg.n_dense_layers
 
-    def _self_block_full(self, lp, x, positions):
+        def moe(h):
+            out, aux, counts = moe_mod.moe_ffn(
+                params["moe"], h, cfg, jnp.maximum(i - n_dense, 0),
+                valid=valid, backend=backend, shd=self.shd)
+            return out, aux.astype(jnp.float32), counts
+        if not n_dense:
+            return moe(h)
+
+        def dense(h):
+            dp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, jnp.minimum(i, n_dense - 1), keepdims=False),
+                params["dense"])
+            return (swiglu(h, dp["w_gate"], dp["w_up"], dp["w_down"],
+                           self.shd), jnp.float32(0.0),
+                    jnp.zeros((cfg.layout_n_experts,), jnp.int32))
+        return jax.lax.cond(i < n_dense, dense, moe, h)
+
+    def _expert_counts(self, state, counts):
+        """The state's per-MoE-layer expert counter [Lm, E] plus a
+        step's per-layer counts [L, E] (a state without one is left
+        as it is)."""
+        if counts is None or "expert_tokens" not in state:
+            return {}
+        return {"expert_tokens": state["expert_tokens"]
+                + counts[self.cfg.n_dense_layers:]}
+
+    def _self_block_full(self, params, lp, i, x, positions):
         """Training/prefill layer; returns (x, (k, v or latent), aux)."""
         cfg = self.cfg
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
@@ -259,10 +299,10 @@ class DecoderModel(Model):
             kv = (k, v)
         x = self.shd(x + o, "batch", "seq_res", None)
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        f, aux = self._ffn(lp, h)
+        f, aux, _ = self._ffn(params, lp, i, h)
         return self.shd(x + f, "batch", "seq_res", None), kv, aux
 
-    def _self_block_decode(self, lp, x, kv, lengths):
+    def _self_block_decode(self, params, lp, i, x, kv, lengths):
         """Decode layer; x [B,1,D]; kv = per-layer cache slices."""
         cfg = self.cfg
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
@@ -294,7 +334,7 @@ class DecoderModel(Model):
             new_kv = (k_c, v_c, ks_c, vs_c)
             x = x + o
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            f, _ = self._ffn(lp, h)
+            f, _, _ = self._ffn(params, lp, i, h)
             return x + f, new_kv
         else:
             k_c, v_c = kv
@@ -311,7 +351,7 @@ class DecoderModel(Model):
             new_kv = (k_c, v_c)
         x = x + o
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        f, _ = self._ffn(lp, h)
+        f, _, _ = self._ffn(params, lp, i, h)
         return x + f, new_kv
 
     def _cross_block(self, cp, x, xk, xv):
@@ -336,8 +376,9 @@ class DecoderModel(Model):
         positions = jnp.arange(s)[None, :]
         aux_total = 0.0
 
-        def layer_fn(x, lp):
-            x, kv, aux = self._self_block_full(lp, x, positions)
+        def layer_fn(x, inp):
+            lp, i = inp
+            x, kv, aux = self._self_block_full(params, lp, i, x, positions)
             return x, (kv if collect_cache else None, aux)
 
         body = jax.checkpoint(layer_fn) if remat else layer_fn
@@ -355,7 +396,7 @@ class DecoderModel(Model):
                 cp, sp = gp
                 xk, xv = self._cross_kv(cp, patches_e)
                 x = self._cross_block(cp, x, xk, xv)
-                x, outs = jax.lax.scan(body, x, sp)
+                x, outs = jax.lax.scan(body, x, (sp, None))
                 return x, outs
 
             gbody = jax.checkpoint(group_fn) if remat else group_fn
@@ -366,7 +407,7 @@ class DecoderModel(Model):
                     lambda a: a.reshape((-1,) + a.shape[2:]), caches)
         else:
             x, (caches, auxes) = jax.lax.scan(
-                body, x, params["layers"],
+                body, x, (params["layers"], jnp.arange(cfg.n_layers)),
                 unroll=min(self.scan_unroll, cfg.n_layers))
         aux_total = jnp.mean(auxes) if cfg.n_experts > 0 else 0.0
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
@@ -420,7 +461,7 @@ class DecoderModel(Model):
             (lat_pre,) = prefix_kv
 
             def layer_fn(x, inp):
-                lp, lpre = inp
+                lp, lpre, i = inp
                 h = rms_norm(x, lp["ln1"], cfg.norm_eps)
                 q_nope, q_rope, lat_new = attn.mla_project(
                     lp["attn"], h, positions, cfg, self.shd)
@@ -434,22 +475,24 @@ class DecoderModel(Model):
                     [k, jnp.broadcast_to(k_rope[:, :, None, :],
                                          k.shape[:3] + (dr,))], axis=-1)
                 o = attn.causal_attention(q, k, v, q_offset=q_offset,
+                                          scale=cfg.mla_softmax_scale,
                                           shd=self.shd)
                 o = jnp.einsum("bshk,hkd->bsd", o, lp["attn"]["wo"])
                 x = x + o
                 h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-                f, _ = self._ffn(lp, h)
+                f, _, _ = self._ffn(params, lp, i, h)
                 return x + f, lat_new
 
-            x, lat_suffix = jax.lax.scan(layer_fn, x,
-                                         (params["layers"], lat_pre))
+            x, lat_suffix = jax.lax.scan(
+                layer_fn, x,
+                (params["layers"], lat_pre, jnp.arange(cfg.n_layers)))
             x = rms_norm(x, params["ln_f"], cfg.norm_eps)
             return self._logits(params, x[:, -1]), (lat_suffix,)
 
         k_pre, v_pre = prefix_kv
 
         def layer_fn(x, inp):
-            lp, kp, vp = inp
+            lp, kp, vp, i = inp
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg,
                                        shd=self.shd)
@@ -463,11 +506,12 @@ class DecoderModel(Model):
             o = jnp.einsum("bshk,hkd->bsd", o, lp["attn"]["wo"])
             x = x + o
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            f, _ = self._ffn(lp, h)
+            f, _, _ = self._ffn(params, lp, i, h)
             return x + f, (k, v)
 
-        x, (ks, vs) = jax.lax.scan(layer_fn, x,
-                                   (params["layers"], k_pre, v_pre))
+        x, (ks, vs) = jax.lax.scan(
+            layer_fn, x,
+            (params["layers"], k_pre, v_pre, jnp.arange(cfg.n_layers)))
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         return self._logits(params, x[:, -1]), (ks, vs)
 
@@ -476,15 +520,17 @@ class DecoderModel(Model):
         b = tokens.shape[0]
         x = self.shd.embed_lookup(params["embed"], tokens)[:, None, :]
         lengths = state["lengths"]
+        layer_ids = jnp.arange(cfg.n_layers)
 
         if cfg.attention_variant == MLA:
             def layer_fn(x, inp):
-                lp, latent = inp
-                x, (latent,) = self._self_block_decode(lp, x, (latent,),
-                                                       lengths)
+                lp, latent, i = inp
+                x, (latent,) = self._self_block_decode(
+                    params, lp, i, x, (latent,), lengths)
                 return x, latent
-            x, latents = jax.lax.scan(layer_fn, x,
-                                      (params["layers"], state["latent"]))
+            x, latents = jax.lax.scan(
+                layer_fn, x,
+                (params["layers"], state["latent"], layer_ids))
             new_state = {**state, "latent": latents,
                          "lengths": lengths + 1}
         elif cfg.family == FAMILY_VLM:
@@ -499,7 +545,8 @@ class DecoderModel(Model):
 
             def layer_fn(x, inp):
                 lp, kv = inp
-                x, kv = self._self_block_decode(lp, x, kv, lengths)
+                x, kv = self._self_block_decode(params, lp, None, x, kv,
+                                                lengths)
                 return x, kv
 
             def group_fn(x, gp):
@@ -517,23 +564,25 @@ class DecoderModel(Model):
                          "lengths": lengths + 1}
         elif self.kv_dtype == "int8":
             def layer_fn(x, inp):
-                lp, k_c, v_c, ks_c, vs_c = inp
+                lp, k_c, v_c, ks_c, vs_c, i = inp
                 x, kv = self._self_block_decode(
-                    lp, x, (k_c, v_c, ks_c, vs_c), lengths)
+                    params, lp, i, x, (k_c, v_c, ks_c, vs_c), lengths)
                 return x, kv
             x, (ks, vs, kss, vss) = jax.lax.scan(
                 layer_fn, x, (params["layers"], state["k"], state["v"],
-                              state["k_scale"], state["v_scale"]))
+                              state["k_scale"], state["v_scale"],
+                              layer_ids))
             new_state = {**state, "k": ks, "v": vs, "k_scale": kss,
                          "v_scale": vss, "lengths": lengths + 1}
         else:
             def layer_fn(x, inp):
-                lp, k_c, v_c = inp
-                x, (k_c, v_c) = self._self_block_decode(lp, x, (k_c, v_c),
-                                                        lengths)
+                lp, k_c, v_c, i = inp
+                x, (k_c, v_c) = self._self_block_decode(
+                    params, lp, i, x, (k_c, v_c), lengths)
                 return x, (k_c, v_c)
             x, (ks, vs) = jax.lax.scan(
-                layer_fn, x, (params["layers"], state["k"], state["v"]))
+                layer_fn, x,
+                (params["layers"], state["k"], state["v"], layer_ids))
             new_state = {**state, "k": ks, "v": vs, "lengths": lengths + 1}
 
         x = rms_norm(x[:, 0], params["ln_f"], cfg.norm_eps)
@@ -558,7 +607,9 @@ class DecoderModel(Model):
         block table via the paged attention ops (table entry 0 is the
         caller's scratch page for inactive batch rows).  ``backend``
         selects the kernel backend (``kernels/backend.py``: compiled
-        Pallas on TPU / jitted XLA gathers elsewhere by default).
+        Pallas on TPU / jitted XLA gathers elsewhere by default).  A MoE
+        model adds the step's routed tokens to ``state["expert_tokens"]``
+        [Lm, E] where the state holds that counter.
         """
         from repro.kernels import ops
 
@@ -572,13 +623,16 @@ class DecoderModel(Model):
         page_ids = bt[jnp.arange(b), lengths // page]
         offs = lengths % page
         new_len = lengths + 1
+        # a decoding row has its first page mapped; idle rows read the
+        # scratch page and stay out of the experts and their counts
+        valid = (bt[:, 0] > 0)[:, None]
+        layer_ids = jnp.arange(cfg.n_layers)
 
         if cfg.attention_variant == MLA:
-            dl, dr = cfg.d_latent, cfg.d_rope
-            scale = 1.0 / math.sqrt(cfg.hd + dr)
+            dl = cfg.d_latent
 
             def layer_fn(x, inp):
-                lp, latp = inp
+                lp, latp, i = inp
                 h = rms_norm(x, lp["ln1"], cfg.norm_eps)
                 q_nope, q_rope, new_latent = attn.mla_project(
                     lp["attn"], h, lengths[:, None], cfg)
@@ -589,21 +643,24 @@ class DecoderModel(Model):
                 q_lat = jnp.einsum("bshk,lhk->bshl", q_nope,
                                    lp["attn"]["w_uk"])
                 ctx = ops.mla_decode(q_lat[:, 0], q_rope[:, 0], latp, bt,
-                                     new_len, d_latent=dl, scale=scale,
+                                     new_len, d_latent=dl,
+                                     scale=cfg.mla_softmax_scale,
                                      backend=backend)
                 out = jnp.einsum("bhl,lhk->bhk", ctx, lp["attn"]["w_uv"])
                 o = jnp.einsum("bhk,hkd->bd", out, lp["attn"]["wo"])[:, None]
                 x = x + o
                 h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-                f, _ = self._ffn(lp, h)
-                return x + f, latp
+                f, _, counts = self._ffn(params, lp, i, h, valid, backend)
+                return x + f, (latp, counts)
 
-            x, lats = jax.lax.scan(layer_fn, x,
-                                   (params["layers"], state["latent_pages"]))
-            new_state = {**state, "latent_pages": lats, "lengths": new_len}
+            x, (lats, counts) = jax.lax.scan(
+                layer_fn, x,
+                (params["layers"], state["latent_pages"], layer_ids))
+            new_state = {**state, "latent_pages": lats, "lengths": new_len,
+                         **self._expert_counts(state, counts)}
         else:
             def layer_fn(x, inp):
-                lp, kp, vp = inp
+                lp, kp, vp, i = inp
                 h = rms_norm(x, lp["ln1"], cfg.norm_eps)
                 q, k_new, v_new = attn.project_qkv(lp["attn"], h,
                                                    lengths[:, None], cfg,
@@ -618,14 +675,16 @@ class DecoderModel(Model):
                 o = jnp.einsum("bhk,hkd->bd", o, lp["attn"]["wo"])[:, None]
                 x = x + o
                 h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-                f, _ = self._ffn(lp, h)
-                return x + f, (kp, vp)
+                f, _, counts = self._ffn(params, lp, i, h, valid, backend)
+                return x + f, (kp, vp, counts)
 
-            x, (kps, vps) = jax.lax.scan(
+            x, (kps, vps, counts) = jax.lax.scan(
                 layer_fn, x,
-                (params["layers"], state["k_pages"], state["v_pages"]))
+                (params["layers"], state["k_pages"], state["v_pages"],
+                 layer_ids))
             new_state = {**state, "k_pages": kps, "v_pages": vps,
-                         "lengths": new_len}
+                         "lengths": new_len,
+                         **self._expert_counts(state, counts)}
 
         x = rms_norm(x[:, 0], params["ln_f"], cfg.norm_eps)
         return self._logits(params, x), new_state
@@ -646,7 +705,11 @@ class DecoderModel(Model):
         the chunk's per-layer KV ({"k"/"v"} [L,1,C,Hkv,hd] or
         {"latent"} [L,1,C,dl+dr]) for the caller to scatter into the
         pool via ``PagedKVCache.write_chunk`` — logits are never needed:
-        the first decode step consumes the final prompt token.
+        the first decode step consumes the final prompt token.  With
+        experts the state may hold ``n_valid`` (the chunk's prompt
+        tokens; the rest is padding the experts skip) and the counter
+        ``expert_tokens``, which comes back with the chunk's tokens
+        added.
         """
         from repro.kernels import ops
 
@@ -655,35 +718,40 @@ class DecoderModel(Model):
         x = self._embed(params, tokens)
         positions = offset[:, None] + jnp.arange(c)[None, :]
         bt = state["block_table"]
+        # the chunk's first n_valid tokens are the prompt's, the rest
+        # padding that the experts and their counts leave out
+        valid = (jnp.arange(c)[None, :] < state["n_valid"]
+                 if "n_valid" in state else None)
+        layer_ids = jnp.arange(cfg.n_layers)
 
         if cfg.attention_variant == MLA:
-            dl, dr = cfg.d_latent, cfg.d_rope
-            scale = 1.0 / math.sqrt(cfg.hd + dr)
+            dl = cfg.d_latent
 
             def layer_fn(x, inp):
-                lp, latp = inp
+                lp, latp, i = inp
                 h = rms_norm(x, lp["ln1"], cfg.norm_eps)
                 q_nope, q_rope, latent = attn.mla_project(
                     lp["attn"], h, positions, cfg)
                 q_lat = jnp.einsum("bshk,lhk->bshl", q_nope,
                                    lp["attn"]["w_uk"])
                 ctx = ops.mla_prefill(q_lat, q_rope, latent, latp, bt,
-                                      offset, d_latent=dl, scale=scale,
+                                      offset, d_latent=dl,
+                                      scale=cfg.mla_softmax_scale,
                                       backend=backend)
                 out = jnp.einsum("bshl,lhk->bshk", ctx, lp["attn"]["w_uv"])
                 o = jnp.einsum("bshk,hkd->bsd", out, lp["attn"]["wo"])
                 x = x + o
                 h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-                f, _ = self._ffn(lp, h)
-                return x + f, latent
+                f, _, counts = self._ffn(params, lp, i, h, valid, backend)
+                return x + f, (latent, counts)
 
-            _, lats = jax.lax.scan(layer_fn, x,
-                                   (params["layers"],
-                                    state["latent_pages"]))
-            return {"latent": lats}
+            _, (lats, counts) = jax.lax.scan(
+                layer_fn, x,
+                (params["layers"], state["latent_pages"], layer_ids))
+            return {"latent": lats, **self._expert_counts(state, counts)}
 
         def layer_fn(x, inp):
-            lp, kp, vp = inp
+            lp, kp, vp, i = inp
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg,
                                        shd=NOSHARD)
@@ -695,13 +763,14 @@ class DecoderModel(Model):
             o = jnp.einsum("bshk,hkd->bsd", o, lp["attn"]["wo"])
             x = x + o
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            f, _ = self._ffn(lp, h)
-            return x + f, (k, v)
+            f, _, counts = self._ffn(params, lp, i, h, valid, backend)
+            return x + f, (k, v, counts)
 
-        _, (ks, vs) = jax.lax.scan(
+        _, (ks, vs, counts) = jax.lax.scan(
             layer_fn, x,
-            (params["layers"], state["k_pages"], state["v_pages"]))
-        return {"k": ks, "v": vs}
+            (params["layers"], state["k_pages"], state["v_pages"],
+             layer_ids))
+        return {"k": ks, "v": vs, **self._expert_counts(state, counts)}
 
     def prefill_chunk_seg(self, params, state, tokens, chunk_positions,
                           backend=None):
@@ -721,12 +790,13 @@ class DecoderModel(Model):
         bt = state["block_table"]
 
         x = self._embed(params, tokens)
+        valid = chunk_positions >= 0
+        layer_ids = jnp.arange(cfg.n_layers)
         if cfg.attention_variant == MLA:
-            dl, dr = cfg.d_latent, cfg.d_rope
-            scale = 1.0 / math.sqrt(cfg.hd + dr)
+            dl = cfg.d_latent
 
             def layer_fn(x, inp):
-                lp, latp = inp
+                lp, latp, i = inp
                 h = rms_norm(x, lp["ln1"], cfg.norm_eps)
                 q_nope, q_rope, latent = attn.mla_project(
                     lp["attn"], h, positions, cfg)
@@ -734,21 +804,22 @@ class DecoderModel(Model):
                                    lp["attn"]["w_uk"])
                 ctx = ops.mla_prefill_seg(q_lat, q_rope, latent, latp, bt,
                                           chunk_positions, d_latent=dl,
-                                          scale=scale, backend=backend)
+                                          scale=cfg.mla_softmax_scale,
+                                          backend=backend)
                 out = jnp.einsum("bshl,lhk->bshk", ctx, lp["attn"]["w_uv"])
                 o = jnp.einsum("bshk,hkd->bsd", out, lp["attn"]["wo"])
                 x = x + o
                 h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-                f, _ = self._ffn(lp, h)
-                return x + f, latent
+                f, _, counts = self._ffn(params, lp, i, h, valid, backend)
+                return x + f, (latent, counts)
 
-            _, lats = jax.lax.scan(layer_fn, x,
-                                   (params["layers"],
-                                    state["latent_pages"]))
-            return {"latent": lats}
+            _, (lats, counts) = jax.lax.scan(
+                layer_fn, x,
+                (params["layers"], state["latent_pages"], layer_ids))
+            return {"latent": lats, **self._expert_counts(state, counts)}
 
         def layer_fn(x, inp):
-            lp, kp, vp = inp
+            lp, kp, vp, i = inp
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg,
                                        shd=NOSHARD)
@@ -760,13 +831,14 @@ class DecoderModel(Model):
             o = jnp.einsum("bshk,hkd->bsd", o, lp["attn"]["wo"])
             x = x + o
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            f, _ = self._ffn(lp, h)
-            return x + f, (k, v)
+            f, _, counts = self._ffn(params, lp, i, h, valid, backend)
+            return x + f, (k, v, counts)
 
-        _, (ks, vs) = jax.lax.scan(
+        _, (ks, vs, counts) = jax.lax.scan(
             layer_fn, x,
-            (params["layers"], state["k_pages"], state["v_pages"]))
-        return {"k": ks, "v": vs}
+            (params["layers"], state["k_pages"], state["v_pages"],
+             layer_ids))
+        return {"k": ks, "v": vs, **self._expert_counts(state, counts)}
 
     # -- decode state ----------------------------------------------------------
     def decode_state_specs(self, batch, max_len):

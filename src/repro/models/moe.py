@@ -1,103 +1,139 @@
-"""Mixture-of-Experts FFN with top-k routing (granite-moe archs).
+"""Mixture-of-Experts FFN with dropless top-k routing (granite-moe and
+DeepSeek-V2 configurations).
 
-GShard-style capacity-bounded dispatch.  Tokens are reshaped into
-``[n_groups, group, d]`` with the group dim sharded over the data axes
-(so dispatch never crosses shards), and the top-k slots are processed by
-a sequential k-loop — peak dispatch tensor is O(group * E * C) per k-slot
-instead of O(group * k * E * C).  The group size is kept small (256)
-because the combine tensor scales with group^2 * k * cf.
+Every token reaches each of its top-k experts: there is no capacity, so
+a token's output never depends on which other tokens share its step.
+The router scores in float32; the top-k gate weights are renormalised
+where the config says so (``norm_topk_prob``).  The token-expert
+assignments are sorted by expert and each expert's rows go through a
+grouped matmul (``kernels/ops.py`` ``expert_matmul``: the megablox
+grouped-matmul Pallas kernel on TPU, ``lax.ragged_dot`` elsewhere).
+Always-on shared experts (DeepSeekMoE) run as one SwiGLU of their
+summed width and add to the routed sum.
 
-Expert weights are tensor-parallel over the per-expert hidden dim
-(``mlp`` -> model axis), which divides evenly for any expert count.
+XLA cannot partition the Pallas call, so on a mesh the routed experts
+run under ``shard_map`` (``MeshSharding.expert_map``): each shard
+computes its own tokens against the experts and hidden slice it holds,
+and the partial sums add up over the axes that split them.  Expert
+weights are never gathered.
+
+The MoE layers' weights are stacked [Lm, ...] apart from the layer
+scan's per-layer slices, and ``moe_ffn`` takes the MoE layer's index:
+the TPU kernel then reads that layer's experts where they lie, with no
+sliced copy of them per layer.  Rows that ``valid`` masks out (idle
+decode rows, chunk padding) are neither computed nor counted.
 """
 from __future__ import annotations
 
+import functools
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
-from repro.models.common import NOSHARD, PSpec
-
-GROUP = 256          # tokens per dispatch group
+from repro.models.common import NOSHARD, PSpec, swiglu
 
 
 def moe_pspecs(cfg: ModelConfig) -> Dict[str, PSpec]:
+    """One MoE layer's parameters (the model stacks them over its MoE
+    layers)."""
     d, e, f = cfg.d_model, cfg.layout_n_experts, cfg.moe_ff
-    return {
+    down = 0.02 / math.sqrt(2 * max(cfg.n_layers, 1))
+    out = {
         "router": PSpec((d, e), ("embed", None)),
         "w_gate": PSpec((e, d, f), ("experts", "embed", "mlp")),
         "w_up": PSpec((e, d, f), ("experts", "embed", "mlp")),
-        "w_down": PSpec((e, f, d), ("experts", "mlp", "embed"),
-                        scale=0.02 / math.sqrt(2 * max(cfg.n_layers, 1))),
+        "w_down": PSpec((e, f, d), ("experts", "mlp", "embed"), scale=down),
     }
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        out["shared"] = {
+            "w_gate": PSpec((d, fs), ("embed", "mlp")),
+            "w_up": PSpec((d, fs), ("embed", "mlp")),
+            "w_down": PSpec((fs, d), ("mlp", "embed"), scale=down),
+        }
+    return out
 
 
-def _n_groups(t: int, dp: int) -> int:
-    """Group count: a multiple of the dp degree (so groups shard evenly)
-    with ~GROUP tokens per group."""
-    if t % dp != 0:
-        dp = 1
-    per_shard = t // dp
-    g = dp * max(1, per_shard // GROUP)
-    while t % g != 0:
-        g -= 1
-    return max(1, g)
-
-
-def moe_ffn(p: Dict, x: jax.Array, cfg: ModelConfig,
-            shd=NOSHARD) -> Tuple[jax.Array, jax.Array]:
-    """x [B,S,D] -> (out [B,S,D], aux loss)."""
-    b, s, d = x.shape
-    t = b * s
-    e, k = cfg.layout_n_experts, cfg.top_k
-    dp = getattr(shd, "dp_size", lambda: 1)()
-    ng = _n_groups(t, dp)
-    group = t // ng
-    capacity = max(4, int(math.ceil(group * k / cfg.n_experts
-                                    * cfg.capacity_factor)))
-
-    xg = x.reshape(ng, group, d)
-    xg = shd(xg, "moe_groups", None, None)
-    logits = jnp.einsum("gtd,de->gte", xg, p["router"]).astype(jnp.float32)
+def route(router: jax.Array, x: jax.Array, cfg: ModelConfig
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x [T, D] -> (gate weights [T, k] f32, experts [T, k], probs [T, E])."""
+    logits = jnp.einsum("td,de->te", x, router,
+                        preferred_element_type=jnp.float32)
+    e = logits.shape[-1]
     if e != cfg.n_experts:
         # padded experts (expert-parallel layout): never routable
-        pad_mask = jnp.where(jnp.arange(e) < cfg.n_experts, 0.0, -1e9)
-        logits = logits + pad_mask
-    probs = jax.nn.softmax(logits, axis=-1)                 # [G,t,E]
-    top_p, top_i = jax.lax.top_k(probs, k)                  # [G,t,k]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        logits = logits + jnp.where(jnp.arange(e) < cfg.n_experts, 0.0,
+                                    -1e9)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_i, probs
 
-    counts = jnp.zeros((ng, 1, e), jnp.float32)             # used capacity
-    dispatch = jnp.zeros((ng, group, e, capacity), jnp.float32)
-    combine = jnp.zeros((ng, group, e, capacity), jnp.float32)
-    for j in range(k):                                      # sequential slots
-        oh = jax.nn.one_hot(top_i[:, :, j], e, dtype=jnp.float32)  # [G,t,E]
-        pos = counts + jnp.cumsum(oh, axis=1) - oh          # [G,t,E]
-        pos_j = jnp.sum(pos * oh, axis=-1)                  # [G,t]
-        keep = (pos_j < capacity).astype(jnp.float32)
-        pos_oh = jax.nn.one_hot(pos_j.astype(jnp.int32), capacity,
-                                dtype=jnp.float32)          # [G,t,C]
-        disp = jnp.einsum("gte,gtc,gt->gtec", oh, pos_oh, keep)
-        dispatch = dispatch + disp
-        combine = combine + disp * (top_p[:, :, j] * keep)[..., None, None]
-        counts = counts + jnp.sum(oh * keep[..., None], axis=1,
-                                  keepdims=True)
 
-    xin = jnp.einsum("gtec,gtd->gecd", dispatch.astype(x.dtype), xg)
-    xin = shd(xin, "moe_groups", None, None, None)
-    h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xin, p["w_gate"])
-                    .astype(jnp.float32)).astype(x.dtype)
-    h = h * jnp.einsum("gecd,edf->gecf", xin, p["w_up"])
-    h = shd(h, "moe_groups", None, None, "mlp")
-    y = jnp.einsum("gecf,efd->gecd", h, p["w_down"])
-    out = jnp.einsum("gtec,gecd->gtd", combine.astype(x.dtype), y)
-    # load-balance auxiliary loss (Switch eq. 4)
-    frac_tokens = jnp.mean(
-        jax.nn.one_hot(top_i, e, dtype=jnp.float32).sum(axis=2),
-        axis=(0, 1))
-    frac_probs = jnp.mean(probs, axis=(0, 1))
-    aux = e * jnp.sum(frac_tokens * frac_probs) / k
-    return out.reshape(b, s, d), aux
+def routed_sum(xt: jax.Array, top_i: jax.Array, top_p: jax.Array,
+               valid: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+               w_down: jax.Array, layer: jax.Array, e0=0,
+               backend: str = None) -> jax.Array:
+    """The gate-weighted sum [T, D] float32 of each token's routed
+    experts, over the experts ``e0 .. e0 + E'`` that the stacks
+    ``w_*`` [Lm, E', ...] hold (all of them on one device; a shard's
+    own on a mesh, whose partial sums then add up)."""
+    from repro.kernels import ops
+
+    t, k = top_i.shape
+    e = w_gate.shape[1]
+    mine = valid[:, None] & (top_i >= e0) & (top_i < e0 + e)   # [T, k]
+    # assignments sorted by expert; the others (masked rows, experts
+    # held elsewhere) sort last, past every group, so the grouped
+    # matmul never computes them
+    flat = jnp.where(mine, top_i - e0, e).reshape(-1)           # [T*k]
+    sizes = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
+    order = jnp.argsort(flat, stable=True)
+    xs = xt[order // k]                                         # [T*k, D]
+    mm = lambda a, w: ops.expert_matmul(  # noqa: E731
+        a, w, sizes, layer, backend=backend)
+    g = mm(xs, w_gate)
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(xt.dtype) * mm(xs, w_up)
+    ys = mm(h, w_down)                                          # [T*k, D]
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    y = ys[inv].reshape(t, k, -1)
+    w = jnp.where(mine, top_p, 0.0)
+    y = jnp.where(mine[..., None], y.astype(jnp.float32), 0.0)
+    return jnp.einsum("tkd,tk->td", y, w)
+
+
+def moe_ffn(p: Dict, x: jax.Array, cfg: ModelConfig, layer: jax.Array,
+            valid: Optional[jax.Array] = None, backend: str = None,
+            shd=NOSHARD) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x [..., D] through MoE layer ``layer`` of the stacked params ``p``
+    -> (out [..., D], load-balance aux loss, tokens routed to each
+    expert [E] int32).  ``valid`` [...] bool masks rows out.  On a
+    mesh, ``shd.expert_map`` runs the routed experts on each shard's
+    own experts and hidden slice, and sums the partial outputs."""
+    shape = x.shape
+    xt = x.reshape(-1, shape[-1])
+    t, e = xt.shape[0], cfg.layout_n_experts
+    valid = (jnp.ones((t,), bool) if valid is None
+             else jnp.broadcast_to(valid, shape[:-1]).reshape(t))
+    at = lambda a: jax.lax.dynamic_index_in_dim(a, layer,  # noqa: E731
+                                                keepdims=False)
+    top_p, top_i, probs = route(at(p["router"]), xt, cfg)
+    hit = jax.nn.one_hot(top_i, e, dtype=jnp.int32) * valid[:, None, None]
+    counts = jnp.sum(hit, axis=(0, 1))                       # [E]
+    out = shd.expert_map(
+        functools.partial(routed_sum, backend=backend), xt, top_i, top_p,
+        valid, p["w_gate"], p["w_up"], p["w_down"], layer).astype(x.dtype)
+    if "shared" in p:
+        sp = jax.tree.map(at, p["shared"])
+        out = out + swiglu(xt, sp["w_gate"], sp["w_up"], sp["w_down"])
+    # load-balance auxiliary loss (Switch eq. 4), over the valid rows
+    n = jnp.maximum(jnp.sum(valid), 1)
+    frac_tokens = counts / n
+    frac_probs = jnp.sum(probs * valid[:, None], axis=0) / n
+    aux = e * jnp.sum(frac_tokens * frac_probs) / cfg.top_k
+    return out.reshape(shape), aux, counts

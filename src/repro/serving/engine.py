@@ -651,7 +651,7 @@ class ServingEngine:
             arr = jnp.asarray([chunk + [0] * (C - n)], jnp.int32)
             cpos = jnp.asarray([positions + [-1] * (C - n)], jnp.int32)
             state1 = self._prefill_chunk_seg(
-                self.params, self.kv.chunk_state(req.slot), arr, cpos)
+                self.params, self.kv.chunk_state(req.slot, n), arr, cpos)
             self.kv.write_chunk_positions(req.slot, state1, positions)
         # every gap below positions[-1] is now filled, so the contiguous
         # frontier advances to just past it (then over any island there)
@@ -690,7 +690,7 @@ class ServingEngine:
                     arr = jnp.asarray([chunk + [0] * (C - n)], jnp.int32)
                     off = jnp.asarray([req.prefill_pos], jnp.int32)
                     state1 = self._prefill_chunk(
-                        self.params, self.kv.chunk_state(req.slot), arr,
+                        self.params, self.kv.chunk_state(req.slot, n), arr,
                         off)
                     self.kv.write_chunk(req.slot, state1, req.prefill_pos,
                                         n)
@@ -1067,6 +1067,11 @@ class ServingEngine:
                "h2d_bytes": self.kv.h2d_bytes}
         if self.paged:
             out["allocator"] = self.kv.allocator.stats_dict()
+            if self.kv.expert_tokens is not None:
+                # [MoE layer][expert] tokens routed since the engine was
+                # built, counted on the device; read here and only here
+                out["moe_expert_tokens"] = np.asarray(
+                    self.kv.expert_tokens).tolist()
             out["decode_state_reuses"] = self.kv.state_reuses
             out["decode_state_rebuilds"] = self.kv.state_rebuilds
         if self.worker is not None:

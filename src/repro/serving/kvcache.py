@@ -264,6 +264,12 @@ class PagedKVCache(_KVCacheBase):
             shape = (L, self.n_pages, self.page, hkv, cfg.hd)
             self.pools = {"k_pages": jnp.zeros(shape, dtype, device=device),
                           "v_pages": jnp.zeros(shape, dtype, device=device)}
+        # tokens routed to each expert of each MoE layer [Lm, E]: the
+        # decode and prefill-chunk programs add to it on the device, and
+        # the host reads it only for ``ServingEngine.stats()``
+        self.expert_tokens = (
+            jnp.zeros((cfg.n_moe_layers, cfg.layout_n_experts), jnp.int32,
+                      device=device) if cfg.n_experts else None)
         self.tables = np.zeros((n_slots, self.pages_per_slot), np.int32)
         self._mapped = [0] * n_slots           # contiguous mapped page count
         self.slots = [SlotInfo() for _ in range(n_slots)]
@@ -388,6 +394,10 @@ class PagedKVCache(_KVCacheBase):
     # ------------------------------------------------------------------
     # writes
     # ------------------------------------------------------------------
+    def _take_counts(self, state1: Dict) -> None:
+        if "expert_tokens" in state1:
+            self.expert_tokens = state1["expert_tokens"]
+
     def write_range(self, slot: int, state1: Dict, start: int,
                     n_tokens: int) -> None:
         """Scatter a batch-1 KV state into positions [start, start+n),
@@ -418,6 +428,7 @@ class PagedKVCache(_KVCacheBase):
         for key, data in items:
             self.pools[key] = _scatter_pool(self.pools[key], pid_arr,
                                             off_arr, data)
+        self._take_counts(state1)
         self.state_version += 1    # pool arrays replaced
 
     def ensure_pages_at(self, slot: int, page_indices: Sequence[int]) -> None:
@@ -469,6 +480,7 @@ class PagedKVCache(_KVCacheBase):
         for key, data in items:
             self.pools[key] = _scatter_pool(self.pools[key], pid_arr,
                                             off_arr, data)
+        self._take_counts(state1)
         self.state_version += 1    # pool arrays replaced
 
     # ------------------------------------------------------------------
@@ -559,6 +571,8 @@ class PagedKVCache(_KVCacheBase):
                 state = self._cached_state
                 self._cached_state = None  # donated into the closure
                 self.state_reuses += 1
+                if self.expert_tokens is not None:
+                    state["expert_tokens"] = self.expert_tokens
                 return state
         self.state_rebuilds += 1
         tables = self.tables
@@ -573,13 +587,24 @@ class PagedKVCache(_KVCacheBase):
         state = dict(self.pools)
         state["block_tables"] = jnp.asarray(tables)
         state["lengths"] = jnp.asarray(lengths)
+        if self.expert_tokens is not None:
+            state["expert_tokens"] = self.expert_tokens
         return state
 
-    def chunk_state(self, slot: int) -> Dict:
+    def chunk_state(self, slot: int, n_valid: Optional[int] = None) -> Dict:
         """Snapshot for Model.prefill_chunk: the page pools plus this
-        slot's block-table row (batch dim 1)."""
+        slot's block-table row (batch dim 1); with experts, their
+        counter and ``n_valid``, the chunk's count of prompt tokens (the
+        rest is padding the experts skip; by default none is, so a
+        caller that leaves it out computes every row's experts and
+        counts the padding too)."""
         state = dict(self.pools)
         state["block_table"] = jnp.asarray(self.tables[slot:slot + 1])
+        if self.expert_tokens is not None:
+            state["expert_tokens"] = self.expert_tokens
+            state["n_valid"] = jnp.asarray(
+                np.iinfo(np.int32).max if n_valid is None else n_valid,
+                jnp.int32)
         return state
 
     def absorb(self, new_state: Dict,
@@ -591,6 +616,7 @@ class PagedKVCache(_KVCacheBase):
         cached for ``decode_state(reuse=True)`` next step."""
         for key in self.pools:
             self.pools[key] = new_state[key]
+        self._take_counts(new_state)
         self.state_version += 1    # pool arrays replaced
         if decode_slots is not None:
             self._cached_state = new_state

@@ -12,6 +12,12 @@ from repro.kernels.paged_attention import paged_decode_attention
 RNG = np.random.default_rng(0)
 
 
+def _mla_scale(dl, dr):
+    """A softmax scale for the MLA kernels, which take it from their
+    caller: that of a head whose nope part is a quarter of the latent."""
+    return 1.0 / (dl // 4 + dr) ** 0.5
+
+
 def _arr(shape, dtype):
     a = RNG.normal(size=shape).astype(np.float32)
     return jnp.asarray(a, dtype)
@@ -69,9 +75,10 @@ def test_mla_paged_decode_sweep(b, hq, dl, dr, page, pages):
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     ln = jnp.asarray(RNG.integers(1, pages * page, size=b), jnp.int32)
-    out = mla_paged_decode(ql, qr, lat, bt, ln, d_latent=dl,
+    sc = _mla_scale(dl, dr)
+    out = mla_paged_decode(ql, qr, lat, bt, ln, d_latent=dl, scale=sc,
                            interpret=True)
-    exp = ref.mla_paged_decode_ref(ql, qr, lat, bt, ln, dl)
+    exp = ref.mla_paged_decode_ref(ql, qr, lat, bt, ln, dl, scale=sc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=1e-4, atol=1e-4)
 
@@ -163,9 +170,10 @@ def test_mla_paged_prefill_sweep(b, c, hq, dl, dr, page, pages, offs):
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     off = jnp.asarray(offs, jnp.int32)
+    sc = _mla_scale(dl, dr)
     out = mla_paged_prefill(ql, qr, lc, lp, bt, off, d_latent=dl,
-                            interpret=True)
-    exp = ref.mla_paged_prefill_ref(ql, qr, lc, lp, bt, off, dl)
+                            scale=sc, interpret=True)
+    exp = ref.mla_paged_prefill_ref(ql, qr, lc, lp, bt, off, dl, scale=sc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=1e-4, atol=1e-4)
 
@@ -277,9 +285,11 @@ def test_mla_prefill_segments_sweep(b, c, hq, dl, dr, page, pages, rows):
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     cpos = _cpos(rows, c)
+    sc = _mla_scale(dl, dr)
     out = mla_paged_prefill_segments(ql, qr, lc, lp, bt, cpos,
-                                     d_latent=dl, interpret=True)
-    exp = ref.mla_paged_prefill_segments_ref(ql, qr, lc, lp, bt, cpos, dl)
+                                     d_latent=dl, scale=sc, interpret=True)
+    exp = ref.mla_paged_prefill_segments_ref(ql, qr, lc, lp, bt, cpos, dl,
+                                             scale=sc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=1e-4, atol=1e-4)
 
@@ -322,10 +332,12 @@ def test_mla_prefill_segments_degenerate_contiguous():
     offs = (23, 0)
     off = jnp.asarray(offs, jnp.int32)
     cpos = _cpos([[o + i for i in range(c)] for o in offs], c)
+    sc = _mla_scale(dl, dr)
     out_seg = mla_paged_prefill_segments(ql, qr, lc, lp, bt, cpos,
-                                         d_latent=dl, interpret=True)
+                                         d_latent=dl, scale=sc,
+                                         interpret=True)
     out_off = mla_paged_prefill(ql, qr, lc, lp, bt, off, d_latent=dl,
-                                interpret=True)
+                                scale=sc, interpret=True)
     np.testing.assert_array_equal(np.asarray(out_seg), np.asarray(out_off))
 
 

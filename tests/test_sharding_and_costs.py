@@ -89,3 +89,39 @@ def test_batch_shardings_replicate_small_batch():
     sh = shlib.batch_shardings(tree, mesh)
     # batch=1 on size-1 axes: sharded-over-1 == replicated, both legal
     assert sh["tokens"].spec in (P(), P("data"), P(("data",)))
+
+
+@pytest.mark.parametrize("n_e,n_f", [(4, 1), (1, 2), (2, 2)],
+                         ids=["experts", "hidden", "both"])
+def test_routed_experts_add_up_over_shards(n_e, n_f):
+    """What ``MeshSharding.expert_map`` has each shard compute: the
+    routed sum over the experts e0 .. e0 + E/n_e and a 1/n_f slice of
+    each expert's hidden width.  The shards' partial sums add up to the
+    whole layer's, and masked rows get nothing."""
+    import numpy as np
+    from repro.models import moe
+
+    t, k, e, d, f, lm = 24, 3, 8, 16, 12, 2
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    xt = jax.random.normal(ks[0], (t, d), jnp.float32)
+    top_i = jax.random.randint(ks[1], (t, k), 0, e)
+    top_p = jax.random.uniform(ks[2], (t, k))
+    valid = jnp.arange(t) < t - 5
+    wg, wu = (jax.random.normal(kk, (lm, e, d, f)) for kk in
+              jax.random.split(ks[3]))
+    wd = jax.random.normal(ks[4], (lm, e, f, d))
+    layer = jnp.int32(1)
+    whole = moe.routed_sum(xt, top_i, top_p, valid, wg, wu, wd, layer,
+                           backend="xla")
+    es, fs = e // n_e, f // n_f
+    parts = sum(
+        moe.routed_sum(xt, top_i, top_p, valid,
+                       wg[:, a * es:(a + 1) * es, :, b * fs:(b + 1) * fs],
+                       wu[:, a * es:(a + 1) * es, :, b * fs:(b + 1) * fs],
+                       wd[:, a * es:(a + 1) * es, b * fs:(b + 1) * fs],
+                       layer, a * es, backend="xla")
+        for a in range(n_e) for b in range(n_f))
+    # float32 sums taken in another order
+    np.testing.assert_allclose(np.asarray(parts), np.asarray(whole),
+                               rtol=1e-4, atol=1e-4)
+    assert not np.asarray(whole)[t - 5:].any()

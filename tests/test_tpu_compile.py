@@ -99,7 +99,8 @@ def test_paged_prefill_compiles(arch, kernel, positions, one_chip):
 def test_mla_decode_compiles(one_chip):
     hq, dl, dr = DEEPSEEK_V3.n_heads, DEEPSEEK_V3.d_latent, DEEPSEEK_V3.d_rope
     _compile(lambda ql, qr, lp, bt, ln: mla_paged_decode(
-        ql, qr, lp, bt, ln, d_latent=dl, interpret=False), one_chip,
+        ql, qr, lp, bt, ln, d_latent=dl, scale=(128 + dr) ** -0.5,
+        interpret=False), one_chip,
         ((BATCH, hq, dl), jnp.bfloat16), ((BATCH, hq, dr), jnp.bfloat16),
         ((POOL, PAGE, dl + dr), jnp.bfloat16),
         ((BATCH, PAGES), jnp.int32), ((BATCH,), jnp.int32))
@@ -110,9 +111,86 @@ def test_mla_prefill_segments_compiles(one_chip):
     heads exceed the 16 MiB scoped VMEM; the kernel tiles heads."""
     hq, dl, dr = DEEPSEEK_V3.n_heads, DEEPSEEK_V3.d_latent, DEEPSEEK_V3.d_rope
     _compile(lambda ql, qr, lc, lp, bt, cp: mla_paged_prefill_segments(
-        ql, qr, lc, lp, bt, cp, d_latent=dl, interpret=False), one_chip,
+        ql, qr, lc, lp, bt, cp, d_latent=dl, scale=(128 + dr) ** -0.5,
+        interpret=False), one_chip,
         ((BATCH, CHUNK, hq, dl), jnp.bfloat16),
         ((BATCH, CHUNK, hq, dr), jnp.bfloat16),
         ((BATCH, CHUNK, dl + dr), jnp.bfloat16),
         ((POOL, PAGE, dl + dr), jnp.bfloat16),
         ((BATCH, PAGES), jnp.int32), ((BATCH, CHUNK), jnp.int32))
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_expert_gmm_compiles(k, n, one_chip):
+    """DeepSeek-V2-Lite's experts at published widths: 64 a layer, six
+    MoE layers stacked, 64 rows of top-6 assignments; the kernel reads
+    one layer's experts out of the stack by group offset."""
+    from repro.kernels.ops import _expert_gmm_pallas
+    _compile(lambda x, w, gs, layer: _expert_gmm_pallas(
+        x, w, gs, layer, interpret=False), one_chip,
+        ((64 * 6, k), jnp.bfloat16), ((6, 64, k, n), jnp.bfloat16),
+        ((64,), jnp.int32), ((), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """The four chips of the described v5e:2x2 as a (data 1, model 4)
+    mesh."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+
+
+@pytest.mark.parametrize("rules", ["EP_RULES", "BASE_RULES"])
+def test_moe_layer_on_a_mesh_gathers_no_expert_weights(rules, four_chips,
+                                                       monkeypatch):
+    """granite-moe-1b-a400m's MoE layer on four chips, experts split
+    over the model axis (EP) or each expert's hidden slice split (TP):
+    the grouped-matmul kernel runs on each chip's own weights, and no
+    all-gather brings a chip's share of a layer's experts or more."""
+    import re
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed import sharding as shlib
+    from repro.kernels import ops
+    from repro.models import moe
+
+    # the Mosaic kernel, which this CPU process would not pick itself
+    monkeypatch.setattr(ops, "expert_matmul", lambda a, w, gs, layer, **_:
+                        ops._expert_gmm_pallas(a, w, gs, layer,
+                                               interpret=False))
+
+    cfg = get_config("granite-moe-1b-a400m")
+    shd = shlib.MeshSharding(four_chips, getattr(shlib, rules))
+    lm, e, d, f = cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.moe_ff
+    specs = moe.moe_pspecs(cfg)
+    t = 16 * CHUNK
+
+    def arg(shape, dtype, axes):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
+            four_chips, shd.pspec(axes)))
+    p = {"router": arg((lm, d, e), jnp.bfloat16,
+                       ("layers",) + specs["router"].axes),
+         **{w: arg((lm,) + specs[w].shape, jnp.bfloat16,
+                   ("layers",) + specs[w].axes)
+            for w in ("w_gate", "w_up", "w_down")}}
+    x = arg((t, d), jnp.bfloat16, ("batch", None))
+    fn = jax.jit(lambda p, x, layer: moe.moe_ffn(p, x, cfg, layer,
+                                                 shd=shd)[0])
+    text = fn.lower(p, x, jax.ShapeDtypeStruct(
+        (), jnp.int32, sharding=NamedSharding(four_chips, P()))
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    share = e * d * f // 4
+    for line in text.splitlines():
+        if "all-gather" in line and "=" in line:
+            dims = re.search(r"=\s*\(?\w+\[([\d,]*)\]", line)
+            n = 1
+            for v in (dims.group(1).split(",") if dims and dims.group(1)
+                      else []):
+                n *= int(v)
+            assert n < share, line

@@ -13,6 +13,12 @@ RNG = np.random.default_rng(7)
 TOL = dict(rtol=1e-4, atol=1e-4)
 
 
+def _mla_scale(dl, dr):
+    """A softmax scale for the MLA kernels, which take it from their
+    caller: that of a head whose nope part is a quarter of the latent."""
+    return 1.0 / (dl // 4 + dr) ** 0.5
+
+
 def _arr(shape, dtype=jnp.float32):
     return jnp.asarray(RNG.normal(size=shape).astype(np.float32), dtype)
 
@@ -113,13 +119,16 @@ def test_mla_decode_xla_equivalence(b, hq, dl, dr, page, pages):
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     ln = jnp.asarray(RNG.integers(1, pages * page, size=b), jnp.int32)
-    out = ops.mla_decode(ql, qr, lat, bt, ln, d_latent=dl, backend="xla")
+    sc = _mla_scale(dl, dr)
+    out = ops.mla_decode(ql, qr, lat, bt, ln, d_latent=dl, scale=sc,
+                         backend="xla")
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref.mla_paged_decode_ref(
-            ql, qr, lat, bt, ln, dl)), **TOL)
+            ql, qr, lat, bt, ln, dl, scale=sc)), **TOL)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ops.mla_decode(
-            ql, qr, lat, bt, ln, d_latent=dl, backend="interpret")), **TOL)
+            ql, qr, lat, bt, ln, d_latent=dl, scale=sc,
+            backend="interpret")), **TOL)
 
 
 def test_paged_decode_xla_ignores_padded_table_entries():
@@ -206,14 +215,16 @@ def test_mla_prefill_xla_equivalence(b, c, hq, dl, dr, page, pages, offs):
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     off = jnp.asarray(offs, jnp.int32)
-    out = ops.mla_prefill(ql, qr, lc, lp, bt, off, d_latent=dl,
+    sc = _mla_scale(dl, dr)
+    out = ops.mla_prefill(ql, qr, lc, lp, bt, off, d_latent=dl, scale=sc,
                           backend="xla")
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref.mla_paged_prefill_ref(
-            ql, qr, lc, lp, bt, off, dl)), **TOL)
+            ql, qr, lc, lp, bt, off, dl, scale=sc)), **TOL)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ops.mla_prefill(
-            ql, qr, lc, lp, bt, off, d_latent=dl, backend="interpret")),
+            ql, qr, lc, lp, bt, off, d_latent=dl, scale=sc,
+            backend="interpret")),
         **TOL)
 
 
@@ -304,14 +315,15 @@ def test_mla_prefill_seg_xla_equivalence(b, c, hq, dl, dr, page, pages,
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     cpos = _cpos(rows, c)
+    sc = _mla_scale(dl, dr)
     out = ops.mla_prefill_seg(ql, qr, lc, lp, bt, cpos, d_latent=dl,
-                              backend="xla")
+                              scale=sc, backend="xla")
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref.mla_paged_prefill_segments_ref(
-            ql, qr, lc, lp, bt, cpos, dl)), **TOL)
+            ql, qr, lc, lp, bt, cpos, dl, scale=sc)), **TOL)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ops.mla_prefill_seg(
-            ql, qr, lc, lp, bt, cpos, d_latent=dl,
+            ql, qr, lc, lp, bt, cpos, d_latent=dl, scale=sc,
             backend="interpret")), **TOL)
 
 
