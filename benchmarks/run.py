@@ -843,15 +843,16 @@ def kernel_benchmarks(backend: str = None, fast: bool = False) -> None:
     cases = []
     for name, hq, hkv in (("decode_gqa", 8, 2), ("decode_mha", 4, 4),
                           ("decode_mqa", 8, 1)):
-        q, kp, vp = arr((b, hq, hd)), arr((n, page, hkv, hd)), \
-            arr((n, page, hkv, hd))
+        # the ops take the stacked [L, N, page, Hkv, hd] pool and a layer
+        q, kp, vp = arr((b, hq, hd)), arr((1, n, page, hkv, hd)), \
+            arr((1, n, page, hkv, hd))
         bt = table(b, pages, n)
         ln = jnp.asarray(rng.integers(page, pages * page, size=b), jnp.int32)
         cases.append((name,
                       lambda be, q=q, kp=kp, vp=vp, bt=bt, ln=ln:
-                      ops.paged_decode(q, kp, vp, bt, ln, backend=be),
+                      ops.paged_decode(q, kp, vp, bt, ln, 0, backend=be),
                       lambda q=q, kp=kp, vp=vp, bt=bt, ln=ln:
-                      ops.paged_decode_ref(q, kp, vp, bt, ln)))
+                      ops.paged_decode_ref(q, kp[0], vp[0], bt, ln)))
     dl, dr = 64, 16
     msc = (hd + dr) ** -0.5          # the query's nope width is hd
     ql, qr = arr((b, 8, dl)), arr((b, 8, dr))
@@ -868,14 +869,14 @@ def kernel_benchmarks(backend: str = None, fast: bool = False) -> None:
     c, ppages = 64, 3
     np_ = 2 * ppages + 2
     pq, pkc, pvc = arr((2, c, 8, hd)), arr((2, c, 2, hd)), arr((2, c, 2, hd))
-    pkp, pvp = arr((np_, page, 2, hd)), arr((np_, page, 2, hd))
+    pkp, pvp = arr((1, np_, page, 2, hd)), arr((1, np_, page, 2, hd))
     pbt = table(2, ppages, np_)
     off = jnp.asarray([page * 2 + 11, 0], jnp.int32)
     cases.append(("prefill_gqa",
                   lambda be: ops.paged_prefill(pq, pkc, pvc, pkp, pvp,
-                                               pbt, off, backend=be),
-                  lambda: ops.paged_prefill_ref(pq, pkc, pvc, pkp, pvp,
-                                                pbt, off)))
+                                               pbt, off, 0, backend=be),
+                  lambda: ops.paged_prefill_ref(pq, pkc, pvc, pkp[0],
+                                                pvp[0], pbt, off)))
     mql, mqr = arr((2, c, 8, dl)), arr((2, c, 8, dr))
     mlc, mlp = arr((2, c, dl + dr)), arr((np_, page, dl + dr))
     mbt = table(2, ppages, np_)

@@ -34,10 +34,10 @@ from repro.kernels.paged_prefill import (mla_paged_prefill,
 
 # -- jitted Pallas entry points (interpret resolved to a static bool) -------
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths,
+def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, layer,
                          interpret: bool):
     return paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                  lengths, interpret=interpret)
+                                  lengths, layer, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_q",
@@ -59,9 +59,9 @@ def _mla_decode_pallas(q_lat, q_rope, latent_pages, block_tables, lengths,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_prefill_pallas(q, k_chunk, v_chunk, k_pages, v_pages,
-                          block_tables, offsets, interpret: bool):
+                          block_tables, offsets, layer, interpret: bool):
     return paged_prefill_attention(q, k_chunk, v_chunk, k_pages, v_pages,
-                                   block_tables, offsets,
+                                   block_tables, offsets, layer,
                                    interpret=interpret)
 
 
@@ -77,10 +77,10 @@ def _mla_prefill_pallas(q_lat, q_rope, lat_chunk, latent_pages,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_prefill_seg_pallas(q, k_chunk, v_chunk, k_pages, v_pages,
-                              block_tables, chunk_positions,
+                              block_tables, chunk_positions, layer,
                               interpret: bool):
     return paged_prefill_segments(q, k_chunk, v_chunk, k_pages, v_pages,
-                                  block_tables, chunk_positions,
+                                  block_tables, chunk_positions, layer,
                                   interpret=interpret)
 
 
@@ -159,16 +159,18 @@ def expert_matmul(lhs, rhs, group_sizes, layer, backend: str | None = None,
 
 
 
-def paged_decode(q, k_pages, v_pages, block_tables, lengths,
+def paged_decode(q, k_pages, v_pages, block_tables, lengths, layer,
                  backend: str | None = None, interpret: bool | None = None):
     """Paged decode attention (GQA/MHA/MQA): q [B,Hq,hd] over block-table
-    -indirected KV pages -> [B,Hq,hd]."""
+    -indirected KV pages of layer ``layer`` (int32 scalar) of the whole
+    stacked pool k/v_pages [L,N,page,Hkv,hd] -> [B,Hq,hd].  The layer is
+    read in place, never sliced out of the stack."""
     be = resolve_backend(backend, interpret)
     if be == "xla":
         return xla_fallback.paged_decode_attention_xla(
-            q, k_pages, v_pages, block_tables, lengths)
+            q, k_pages, v_pages, block_tables, lengths, layer)
     return _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths,
-                                interpret=(be == "interpret"))
+                                layer, interpret=(be == "interpret"))
 
 
 def flash_causal(q, k, v, block_q: int = 128, block_k: int = 128,
@@ -196,16 +198,18 @@ def mla_decode(q_lat, q_rope, latent_pages, block_tables, lengths,
 
 
 def paged_prefill(q, k_chunk, v_chunk, k_pages, v_pages, block_tables,
-                  offsets, backend: str | None = None,
+                  offsets, layer, backend: str | None = None,
                   interpret: bool | None = None):
     """Chunked prefill: full attention to pool tokens < offset (block
-    table indirection) + causal attention within the chunk."""
+    table indirection into layer ``layer`` of the stacked pool
+    k/v_pages [L,N,page,Hkv,hd]) + causal attention within the chunk."""
     be = resolve_backend(backend, interpret)
     if be == "xla":
         return xla_fallback.paged_prefill_attention_xla(
-            q, k_chunk, v_chunk, k_pages, v_pages, block_tables, offsets)
+            q, k_chunk, v_chunk, k_pages, v_pages, block_tables, offsets,
+            layer)
     return _paged_prefill_pallas(q, k_chunk, v_chunk, k_pages, v_pages,
-                                 block_tables, offsets,
+                                 block_tables, offsets, layer,
                                  interpret=(be == "interpret"))
 
 
@@ -224,21 +228,22 @@ def mla_prefill(q_lat, q_rope, lat_chunk, latent_pages, block_tables,
 
 
 def paged_prefill_seg(q, k_chunk, v_chunk, k_pages, v_pages, block_tables,
-                      chunk_positions, backend: str | None = None,
+                      chunk_positions, layer, backend: str | None = None,
                       interpret: bool | None = None):
     """Segment prefill: per-query absolute positions (``chunk_positions``
     [B,C] int32, ascending valid entries, negative = padding) so one
     chunk can span multiple prompt gaps with resumed pool-resident
-    segments between them.  Queries attend every resident pool token
-    below their own position — excluding the chunk's not-yet-scattered
+    segments between them.  Queries attend every resident pool token of
+    layer ``layer`` (stacked pool k/v_pages [L,N,page,Hkv,hd]) below
+    their own position — excluding the chunk's not-yet-scattered
     positions — plus chunk tokens causally."""
     be = resolve_backend(backend, interpret)
     if be == "xla":
         return xla_fallback.paged_prefill_segments_xla(
             q, k_chunk, v_chunk, k_pages, v_pages, block_tables,
-            chunk_positions)
+            chunk_positions, layer)
     return _paged_prefill_seg_pallas(q, k_chunk, v_chunk, k_pages, v_pages,
-                                     block_tables, chunk_positions,
+                                     block_tables, chunk_positions, layer,
                                      interpret=(be == "interpret"))
 
 

@@ -8,9 +8,14 @@ onto (PagedAttention-compatible, §III-B Tier 0).
 TPU adaptation (vs the CUDA original): the block table is a
 *scalar-prefetch* operand — Pallas resolves each grid step's page index
 on the scalar core before the DMA that stages the page into VMEM, so the
-gather indirection costs nothing on the vector path.  Pages are sized so
-one (page, h_kv, hd) tile fits VMEM alongside the query and the flash
-accumulators; the MXU sees dense [Hq, hd] x [hd, page] contractions.
+gather indirection costs nothing on the vector path.  The pool comes
+whole, stacked over layers [L, N, page, Hkv, hd], with the layer index
+as one more scalar-prefetch operand: the DMA picks page ``bt[b, p]`` of
+that layer in place, so a layer's pool is never sliced out (and copied)
+ahead of the call.  (The int8 kernel below, which the engine does not
+serve, still takes one layer's pool.)  Pages are sized so one (page,
+h_kv, hd) tile fits VMEM alongside the query and the flash accumulators;
+the MXU sees dense [Hq, hd] x [hd, page] contractions.
 
 Grid: (batch, num_pages) — pages iterate innermost (sequential on TPU),
 carrying running flash-softmax stats (m, l, acc) in VMEM scratch.
@@ -34,9 +39,10 @@ def _decode_kernel(
     # scalar-prefetch operands
     block_tables_ref,      # [B, P_max] int32
     lengths_ref,           # [B] int32
+    layer_ref,             # [1] int32 (read by the index maps only)
     # array operands (blocked)
     q_ref,                 # [1, Hq, hd]
-    k_ref,                 # [1, page, Hkv, hd]
+    k_ref,                 # [1, page, Hkv, hd]  page of layer ``layer``
     v_ref,                 # [1, page, Hkv, hd]
     # outputs
     o_ref,                 # [1, Hq, hd]
@@ -91,10 +97,16 @@ def _decode_kernel(
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_tables: jax.Array,
-                           lengths: jax.Array, *,
+                           lengths: jax.Array, layer, *,
                            interpret: bool | None = None) -> jax.Array:
-    """q [B,Hq,hd]; k/v_pages [N,page,Hkv,hd]; block_tables [B,P] int32;
-    lengths [B] int32 -> out [B,Hq,hd].
+    """q [B,Hq,hd]; k/v_pages [L,N,page,Hkv,hd], the whole stacked pool;
+    block_tables [B,P] int32; lengths [B] int32; layer, an int32 scalar
+    -> out [B,Hq,hd], attention over layer ``layer``'s pages.
+
+    The layer index is a scalar-prefetch operand: each grid step DMAs
+    page ``bt[b, p]`` of layer ``layer`` straight from the stack, so the
+    caller never slices a layer out of the pool (a slice would be
+    copied first).
 
     ``interpret`` defaults through ``backend.resolve_interpret``:
     compiled on TPU, interpreter elsewhere (True forces the Python-grid
@@ -103,23 +115,27 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     """
     interpret = resolve_interpret(interpret)
     b, hq, hd = q.shape
-    n, page, hkv, _ = k_pages.shape
+    _, n, page, hkv, _ = k_pages.shape
     p_max = block_tables.shape[1]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     # scratch: running max / denom / accumulator live in VMEM across the
     # sequential page iterations
     from jax.experimental.pallas import tpu as pltpu
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, p_max),
         in_specs=[
-            pl.BlockSpec((1, hq, hd), lambda bi, pi, bt, ln: (bi, 0, 0)),
-            pl.BlockSpec((1, page, hkv, hd),
-                         lambda bi, pi, bt, ln: (bt[bi, pi], 0, 0, 0)),
-            pl.BlockSpec((1, page, hkv, hd),
-                         lambda bi, pi, bt, ln: (bt[bi, pi], 0, 0, 0)),
+            pl.BlockSpec((1, hq, hd), lambda bi, pi, bt, ln, li: (bi, 0, 0)),
+            pl.BlockSpec((None, 1, page, hkv, hd),
+                         lambda bi, pi, bt, ln, li:
+                         (li[0], bt[bi, pi], 0, 0, 0)),
+            pl.BlockSpec((None, 1, page, hkv, hd),
+                         lambda bi, pi, bt, ln, li:
+                         (li[0], bt[bi, pi], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, hq, hd), lambda bi, pi, bt, ln: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, hq, hd),
+                               lambda bi, pi, bt, ln, li: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((hq, 1), jnp.float32),
             pltpu.VMEM((hq, 1), jnp.float32),
@@ -132,7 +148,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hq, hd), q.dtype),
         interpret=interpret,
     )
-    return kernel(block_tables, lengths, q, k_pages, v_pages)
+    return kernel(block_tables, lengths, layer, q, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,9 @@ against itself with a causal mask and writes the output.  Flash softmax
 stats (m, l, acc) persist in VMEM scratch across the sequential page
 iterations; the block table and per-row pool limits are scalar-prefetch
 operands, so page resolution happens on the scalar core ahead of the
-DMA.
+DMA.  The GQA kernels take the whole stacked pool [L, N, page, Hkv, hd]
+and the layer index as a third scalar-prefetch operand, and DMA each
+page of that layer straight from the stack.
 
 Grouped-query layout: the wrapper lays q out as [B, Hkv, C·g, hd]
 (row r of KV head h is query r // g, head h·g + r % g), so every kernel
@@ -97,12 +99,13 @@ def _prefill_seg_kernel(
     # scalar-prefetch operands
     block_tables_ref,      # [B, P] int32
     limits_ref,            # [B] int32  (resident pool tokens attendable)
+    layer_ref,             # [1] int32  (read by the index maps only)
     # array operands (blocked)
     rpos_ref,              # [1, R, 1] int32  absolute position of each row
     q_ref,                 # [1, Hkv, R, hd]  R = C·g rows per KV head
     kc_ref,                # [1, C, Hkv, hd]  chunk KV (not yet in the pool)
     vc_ref,                # [1, C, Hkv, hd]
-    kp_ref,                # [1, page, Hkv, hd]  pool page
+    kp_ref,                # [1, page, Hkv, hd]  pool page of layer ``layer``
     vp_ref,                # [1, page, Hkv, hd]
     # outputs
     o_ref,                 # [1, Hkv, R, hd]
@@ -168,10 +171,13 @@ def _prefill_seg_kernel(
 def paged_prefill_segments(q: jax.Array, k_chunk: jax.Array,
                            v_chunk: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_tables: jax.Array,
-                           chunk_positions: jax.Array, *,
+                           chunk_positions: jax.Array, layer, *,
                            interpret: bool | None = None) -> jax.Array:
-    """q [B,C,Hq,hd]; k/v_chunk [B,C,Hkv,hd]; k/v_pages [N,page,Hkv,hd];
-    block_tables [B,P] int32; chunk_positions [B,C] int32 -> [B,C,Hq,hd].
+    """q [B,C,Hq,hd]; k/v_chunk [B,C,Hkv,hd]; k/v_pages
+    [L,N,page,Hkv,hd], the whole stacked pool; block_tables [B,P] int32;
+    chunk_positions [B,C] int32; layer, an int32 scalar -> [B,C,Hq,hd].
+    Pool pages are read from layer ``layer`` of the stack in place (a
+    scalar-prefetch operand of the page index map), never sliced out.
 
     Query i of request b sits at absolute position chunk_positions[b, i]
     (strictly ascending among valid entries; negative = padding): it
@@ -184,30 +190,32 @@ def paged_prefill_segments(q: jax.Array, k_chunk: jax.Array,
     """
     interpret = resolve_interpret(interpret)
     b, c, hq, hd = q.shape
-    n, page, hkv, _ = k_pages.shape
+    _, n, page, hkv, _ = k_pages.shape
     p_max = block_tables.shape[1]
     g = hq // hkv
     r = c * g
     limits = _pool_limits(chunk_positions, c)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     rpos = jnp.repeat(chunk_positions.astype(jnp.int32), g,
                       axis=1)[:, :, None]                 # [B, R, 1]
     qh = q.reshape(b, c, hkv, g, hd).transpose(0, 2, 1, 3, 4).reshape(
         b, hkv, r, hd)
     page_idx = _clamped_page_idx(page, p_max)
-    row_block = lambda bi, pi, bt, lim: (bi, 0, 0, 0)       # noqa: E731
+    row_block = lambda bi, pi, bt, lim, li: (bi, 0, 0, 0)   # noqa: E731
+
+    def pool_block(bi, pi, bt, lim, li):
+        return (li[0], page_idx(bi, pi, bt, lim), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, p_max + 1),
         in_specs=[
-            pl.BlockSpec((1, r, 1), lambda bi, pi, bt, lim: (bi, 0, 0)),
+            pl.BlockSpec((1, r, 1), lambda bi, pi, bt, lim, li: (bi, 0, 0)),
             pl.BlockSpec((1, hkv, r, hd), row_block),
             pl.BlockSpec((1, c, hkv, hd), row_block),
             pl.BlockSpec((1, c, hkv, hd), row_block),
-            pl.BlockSpec((1, page, hkv, hd),
-                         lambda *a: (page_idx(*a), 0, 0, 0)),
-            pl.BlockSpec((1, page, hkv, hd),
-                         lambda *a: (page_idx(*a), 0, 0, 0)),
+            pl.BlockSpec((None, 1, page, hkv, hd), pool_block),
+            pl.BlockSpec((None, 1, page, hkv, hd), pool_block),
         ],
         out_specs=pl.BlockSpec((1, hkv, r, hd), row_block),
         scratch_shapes=[
@@ -223,7 +231,7 @@ def paged_prefill_segments(q: jax.Array, k_chunk: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hkv, r, hd), q.dtype),
         interpret=interpret,
     )
-    out = kernel(block_tables, limits, rpos, qh, k_chunk, v_chunk,
+    out = kernel(block_tables, limits, layer, rpos, qh, k_chunk, v_chunk,
                  k_pages, v_pages)
     return out.reshape(b, hkv, c, g, hd).transpose(0, 2, 1, 3, 4).reshape(
         b, c, hq, hd)
@@ -232,10 +240,12 @@ def paged_prefill_segments(q: jax.Array, k_chunk: jax.Array,
 def paged_prefill_attention(q: jax.Array, k_chunk: jax.Array,
                             v_chunk: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, block_tables: jax.Array,
-                            offsets: jax.Array, *,
+                            offsets: jax.Array, layer, *,
                             interpret: bool | None = None) -> jax.Array:
-    """q [B,C,Hq,hd]; k/v_chunk [B,C,Hkv,hd]; k/v_pages [N,page,Hkv,hd];
-    block_tables [B,P] int32; offsets [B] int32 -> out [B,C,Hq,hd].
+    """q [B,C,Hq,hd]; k/v_chunk [B,C,Hkv,hd]; k/v_pages
+    [L,N,page,Hkv,hd], the whole stacked pool; block_tables [B,P] int32;
+    offsets [B] int32; layer, an int32 scalar -> out [B,C,Hq,hd], over
+    layer ``layer``'s pages.
 
     Query i of request b sits at absolute position offsets[b] + i: it
     attends every pool token < offsets[b] through the block table, plus
@@ -246,7 +256,8 @@ def paged_prefill_attention(q: jax.Array, k_chunk: jax.Array,
     """
     return paged_prefill_segments(
         q, k_chunk, v_chunk, k_pages, v_pages, block_tables,
-        _contiguous_positions(offsets, q.shape[1]), interpret=interpret)
+        _contiguous_positions(offsets, q.shape[1]), layer,
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -31,21 +31,29 @@ import jax.numpy as jnp
 from repro.kernels.ref import NEG_INF
 
 
+def _layer_pages(pages: jax.Array, layer, block_tables: jax.Array):
+    """Pages ``block_tables`` of layer ``layer`` of a stacked pool
+    [L, N, ...], gathered straight from the stack (no layer slice first):
+    [B, P, ...].  Out-of-range ids clamp into the pool."""
+    return pages.at[layer, block_tables].get(mode="clip")
+
+
 @jax.jit
 def paged_decode_attention_xla(q: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array, block_tables: jax.Array,
-                               lengths: jax.Array) -> jax.Array:
-    """q [B,Hq,hd]; k/v_pages [N,page,Hkv,hd]; block_tables [B,P] int32;
-    lengths [B] int32 -> [B,Hq,hd].  GQA/MHA/MQA via head grouping."""
+                               lengths: jax.Array, layer) -> jax.Array:
+    """q [B,Hq,hd]; k/v_pages [L,N,page,Hkv,hd]; block_tables [B,P]
+    int32; lengths [B] int32; layer int32 -> [B,Hq,hd].  GQA/MHA/MQA via
+    head grouping."""
     b, hq, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, _, page, hkv, _ = k_pages.shape
     p_max = block_tables.shape[1]
     g = hq // hkv
     t = p_max * page
 
     # one batched gather per pool: [B, P, page, Hkv, hd] -> [B, T, Hkv, hd]
-    k = jnp.take(k_pages, block_tables, axis=0, mode="clip").reshape(b, t, hkv, hd)
-    v = jnp.take(v_pages, block_tables, axis=0, mode="clip").reshape(b, t, hkv, hd)
+    k = _layer_pages(k_pages, layer, block_tables).reshape(b, t, hkv, hd)
+    v = _layer_pages(v_pages, layer, block_tables).reshape(b, t, hkv, hd)
     qg = q.reshape(b, hkv, g, hd).astype(jnp.float32)
     s = jnp.einsum("bhgd,bthd->bhgt", qg, k.astype(jnp.float32))
     s = s / math.sqrt(hd)
@@ -112,18 +120,21 @@ def mla_paged_decode_xla(q_lat: jax.Array, q_rope: jax.Array,
 def paged_prefill_attention_xla(q: jax.Array, k_chunk: jax.Array,
                                 v_chunk: jax.Array, k_pages: jax.Array,
                                 v_pages: jax.Array, block_tables: jax.Array,
-                                offsets: jax.Array) -> jax.Array:
+                                offsets: jax.Array, layer) -> jax.Array:
     """Chunked prefill: q [B,C,Hq,hd] at absolute positions offset+i
-    attends pool tokens < offset (block-table gather) plus chunk tokens
+    attends pool tokens < offset (block-table gather from layer
+    ``layer`` of the stacked pool [L,N,page,Hkv,hd]) plus chunk tokens
     j <= i.  The chunk KV is dense — not yet scattered into the pool."""
     b, c, hq, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, _, page, hkv, _ = k_pages.shape
     p_max = block_tables.shape[1]
     g = hq // hkv
     t_prior = p_max * page
 
-    kp = jnp.take(k_pages, block_tables, axis=0, mode="clip").reshape(b, t_prior, hkv, hd)
-    vp = jnp.take(v_pages, block_tables, axis=0, mode="clip").reshape(b, t_prior, hkv, hd)
+    kp = _layer_pages(k_pages, layer, block_tables).reshape(
+        b, t_prior, hkv, hd)
+    vp = _layer_pages(v_pages, layer, block_tables).reshape(
+        b, t_prior, hkv, hd)
     k = jnp.concatenate([kp, k_chunk], axis=1)       # [B, T, Hkv, hd]
     v = jnp.concatenate([vp, v_chunk], axis=1)
     qg = q.reshape(b, c, hkv, g, hd).astype(jnp.float32)
@@ -145,22 +156,26 @@ def paged_prefill_attention_xla(q: jax.Array, k_chunk: jax.Array,
 def paged_prefill_segments_xla(q: jax.Array, k_chunk: jax.Array,
                                v_chunk: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array, block_tables: jax.Array,
-                               chunk_positions: jax.Array) -> jax.Array:
+                               chunk_positions: jax.Array,
+                               layer) -> jax.Array:
     """Segment prefill: query i of row b sits at absolute position
     ``chunk_positions[b, i]`` (ascending valid entries; negative =
-    padding) and attends every resident pool token below its position —
-    excluding the chunk's own not-yet-scattered positions — plus chunk
-    tokens j <= i.  Generalizes ``paged_prefill_attention_xla`` to a
-    chunk spanning multiple prompt gaps with resumed (pool-resident)
-    segments between them."""
+    padding) and attends every resident pool token of layer ``layer``
+    (stacked pool [L,N,page,Hkv,hd]) below its position — excluding the
+    chunk's own not-yet-scattered positions — plus chunk tokens j <= i.
+    Generalizes ``paged_prefill_attention_xla`` to a chunk spanning
+    multiple prompt gaps with resumed (pool-resident) segments between
+    them."""
     b, c, hq, hd = q.shape
-    _, page, hkv, _ = k_pages.shape
+    _, _, page, hkv, _ = k_pages.shape
     p_max = block_tables.shape[1]
     g = hq // hkv
     t_prior = p_max * page
 
-    kp = jnp.take(k_pages, block_tables, axis=0, mode="clip").reshape(b, t_prior, hkv, hd)
-    vp = jnp.take(v_pages, block_tables, axis=0, mode="clip").reshape(b, t_prior, hkv, hd)
+    kp = _layer_pages(k_pages, layer, block_tables).reshape(
+        b, t_prior, hkv, hd)
+    vp = _layer_pages(v_pages, layer, block_tables).reshape(
+        b, t_prior, hkv, hd)
     k = jnp.concatenate([kp, k_chunk], axis=1)       # [B, T, Hkv, hd]
     v = jnp.concatenate([vp, v_chunk], axis=1)
     qg = q.reshape(b, c, hkv, g, hd).astype(jnp.float32)

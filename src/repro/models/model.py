@@ -605,9 +605,14 @@ class DecoderModel(Model):
         int32 + "lengths" [B] int32.  The new token's KV is scattered
         into each request's current page; attention reads through the
         block table via the paged attention ops (table entry 0 is the
-        caller's scratch page for inactive batch rows).  ``backend``
-        selects the kernel backend (``kernels/backend.py``: compiled
-        Pallas on TPU / jitted XLA gathers elsewhere by default).  A MoE
+        caller's scratch page for inactive batch rows).  The GQA pools
+        are carried whole through the layer scan: layer i writes its
+        token into the stack in place (``.at[i, page, off]``) and the
+        kernels read layer i's pages from the stack by index, so no
+        layer is sliced out or stacked back; the carried pools are the
+        new state.  ``backend`` selects the kernel backend
+        (``kernels/backend.py``: compiled Pallas on TPU / jitted XLA
+        gathers elsewhere by default).  A MoE
         model adds the step's routed tokens to ``state["expert_tokens"]``
         [Lm, E] where the state holds that counter.
         """
@@ -659,15 +664,18 @@ class DecoderModel(Model):
             new_state = {**state, "latent_pages": lats, "lengths": new_len,
                          **self._expert_counts(state, counts)}
         else:
-            def layer_fn(x, inp):
-                lp, kp, vp, i = inp
+            def layer_fn(carry, inp):
+                x, kp, vp = carry
+                lp, i = inp
                 h = rms_norm(x, lp["ln1"], cfg.norm_eps)
                 q, k_new, v_new = attn.project_qkv(lp["attn"], h,
                                                    lengths[:, None], cfg,
                                                    shd=NOSHARD)
-                kp = kp.at[page_ids, offs].set(k_new[:, 0].astype(kp.dtype))
-                vp = vp.at[page_ids, offs].set(v_new[:, 0].astype(vp.dtype))
-                o = ops.paged_decode(q[:, 0], kp, vp, bt, new_len,
+                kp = kp.at[i, page_ids, offs].set(
+                    k_new[:, 0].astype(kp.dtype))
+                vp = vp.at[i, page_ids, offs].set(
+                    v_new[:, 0].astype(vp.dtype))
+                o = ops.paged_decode(q[:, 0], kp, vp, bt, new_len, i,
                                      backend=backend)
                 mask = attn.head_mask(cfg, o.dtype)
                 if mask is not None:
@@ -676,12 +684,11 @@ class DecoderModel(Model):
                 x = x + o
                 h = rms_norm(x, lp["ln2"], cfg.norm_eps)
                 f, _, counts = self._ffn(params, lp, i, h, valid, backend)
-                return x + f, (kp, vp, counts)
+                return (x + f, kp, vp), counts
 
-            x, (kps, vps, counts) = jax.lax.scan(
-                layer_fn, x,
-                (params["layers"], state["k_pages"], state["v_pages"],
-                 layer_ids))
+            (x, kps, vps), counts = jax.lax.scan(
+                layer_fn, (x, state["k_pages"], state["v_pages"]),
+                (params["layers"], layer_ids))
             new_state = {**state, "k_pages": kps, "v_pages": vps,
                          "lengths": new_len,
                          **self._expert_counts(state, counts)}
@@ -701,7 +708,9 @@ class DecoderModel(Model):
         [1] int32 — tokens already written to this slot's pages; state:
         {"k_pages"/"v_pages"} (or MLA {"latent_pages"}) + "block_table"
         [1, P] int32.  Attention is causal within the chunk and full
-        over pool tokens < offset (kernels/paged_prefill.py).  Returns
+        over pool tokens < offset (kernels/paged_prefill.py); the GQA
+        kernels read layer i's pages from the whole stacked pool by
+        index, so the pool is never sliced per layer.  Returns
         the chunk's per-layer KV ({"k"/"v"} [L,1,C,Hkv,hd] or
         {"latent"} [L,1,C,dl+dr]) for the caller to scatter into the
         pool via ``PagedKVCache.write_chunk`` — logits are never needed:
@@ -750,12 +759,14 @@ class DecoderModel(Model):
                 (params["layers"], state["latent_pages"], layer_ids))
             return {"latent": lats, **self._expert_counts(state, counts)}
 
+        kp, vp = state["k_pages"], state["v_pages"]
+
         def layer_fn(x, inp):
-            lp, kp, vp, i = inp
+            lp, i = inp
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg,
                                        shd=NOSHARD)
-            o = ops.paged_prefill(q, k, v, kp, vp, bt, offset,
+            o = ops.paged_prefill(q, k, v, kp, vp, bt, offset, i,
                                   backend=backend)
             mask = attn.head_mask(cfg, o.dtype)
             if mask is not None:
@@ -767,9 +778,7 @@ class DecoderModel(Model):
             return x + f, (k, v, counts)
 
         _, (ks, vs, counts) = jax.lax.scan(
-            layer_fn, x,
-            (params["layers"], state["k_pages"], state["v_pages"],
-             layer_ids))
+            layer_fn, x, (params["layers"], layer_ids))
         return {"k": ks, "v": vs, **self._expert_counts(state, counts)}
 
     def prefill_chunk_seg(self, params, state, tokens, chunk_positions,
@@ -818,13 +827,15 @@ class DecoderModel(Model):
                 (params["layers"], state["latent_pages"], layer_ids))
             return {"latent": lats, **self._expert_counts(state, counts)}
 
+        kp, vp = state["k_pages"], state["v_pages"]
+
         def layer_fn(x, inp):
-            lp, kp, vp, i = inp
+            lp, i = inp
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             q, k, v = attn.project_qkv(lp["attn"], h, positions, cfg,
                                        shd=NOSHARD)
             o = ops.paged_prefill_seg(q, k, v, kp, vp, bt,
-                                      chunk_positions, backend=backend)
+                                      chunk_positions, i, backend=backend)
             mask = attn.head_mask(cfg, o.dtype)
             if mask is not None:
                 o = o * mask              # zero padded layout heads
@@ -835,9 +846,7 @@ class DecoderModel(Model):
             return x + f, (k, v, counts)
 
         _, (ks, vs, counts) = jax.lax.scan(
-            layer_fn, x,
-            (params["layers"], state["k_pages"], state["v_pages"],
-             layer_ids))
+            layer_fn, x, (params["layers"], layer_ids))
         return {"k": ks, "v": vs, **self._expert_counts(state, counts)}
 
     # -- decode state ----------------------------------------------------------

@@ -24,25 +24,31 @@ def _arr(shape, dtype):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,hq,hkv,hd,page,pages", [
-    (2, 8, 2, 64, 64, 4),
-    (3, 4, 4, 32, 32, 3),       # MHA
-    (1, 8, 1, 128, 64, 2),      # MQA
+@pytest.mark.parametrize("b,hq,hkv,hd,page,pages,layers", [
+    (2, 8, 2, 64, 64, 4, 1),
+    (3, 4, 4, 32, 32, 3, 1),       # MHA
+    (1, 8, 1, 128, 64, 2, 1),      # MQA
+    (2, 8, 2, 64, 64, 4, 3),       # each layer of a 3-layer pool
 ])
-def test_paged_decode_sweep(b, hq, hkv, hd, page, pages, dtype):
+def test_paged_decode_sweep(b, hq, hkv, hd, page, pages, layers, dtype):
+    """The kernel reads layer l of the stacked pool [L, N, page, Hkv,
+    hd]: its output is the single-layer oracle's on pool[l]."""
     n = b * pages + 2
     q = _arr((b, hq, hd), dtype)
-    kp = _arr((n, page, hkv, hd), dtype)
-    vp = _arr((n, page, hkv, hd), dtype)
+    kp = _arr((layers, n, page, hkv, hd), dtype)
+    vp = _arr((layers, n, page, hkv, hd), dtype)
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     ln = jnp.asarray(RNG.integers(1, pages * page, size=b), jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, ln, interpret=True)
-    exp = ref.paged_decode_attention_ref(q, kp, vp, bt, ln)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32),
-                               rtol=tol, atol=tol)
+    for layer in range(layers):
+        out = paged_decode_attention(q, kp, vp, bt, ln, layer,
+                                     interpret=True)
+        exp = ref.paged_decode_attention_ref(q, kp[layer], vp[layer], bt,
+                                             ln)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(exp, np.float32),
+                                   rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -88,17 +94,15 @@ def test_paged_decode_handles_ragged_lengths():
     b, hq, hkv, hd, page, pages = 2, 4, 2, 32, 32, 4
     n = b * pages
     q = _arr((b, hq, hd), jnp.float32)
-    kp = _arr((n, page, hkv, hd), jnp.float32)
-    vp = _arr((n, page, hkv, hd), jnp.float32)
+    kp = _arr((1, n, page, hkv, hd), jnp.float32)
+    vp = _arr((1, n, page, hkv, hd), jnp.float32)
     bt = jnp.arange(n, dtype=jnp.int32).reshape(b, pages)
     ln = jnp.asarray([1, 33], jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, ln, interpret=True)
+    out = paged_decode_attention(q, kp, vp, bt, ln, 0, interpret=True)
     # corrupt pages beyond the valid length: output must not change
-    kp2 = kp.at[2:].set(999.0)
-    vp2 = vp.at[2:].set(999.0)
-    kp2 = kp2.at[:, :, :, :].set(
-        jnp.where(jnp.arange(n)[:, None, None, None] >= 2, 999.0, kp))
-    out2 = paged_decode_attention(q, kp2, vp2, bt, ln, interpret=True)
+    kp2 = kp.at[:, 2:].set(999.0)
+    vp2 = vp.at[:, 2:].set(999.0)
+    out2 = paged_decode_attention(q, kp2, vp2, bt, ln, 0, interpret=True)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(out2[0]),
                                rtol=1e-5, atol=1e-5)
 
@@ -131,29 +135,33 @@ def test_int8_paged_decode_vs_oracle_and_fp():
 # chunked prefill over paged KV (kernels/paged_prefill.py)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,c,hq,hkv,hd,page,pages,offs", [
-    (2, 16, 4, 2, 32, 8, 5, (19, 0)),     # unaligned + zero offset
-    (1, 32, 8, 8, 64, 32, 3, (64,)),      # MHA, page-aligned offset
-    (2, 8, 4, 1, 16, 16, 4, (5, 48)),     # MQA
+@pytest.mark.parametrize("b,c,hq,hkv,hd,page,pages,offs,layers", [
+    (2, 16, 4, 2, 32, 8, 5, (19, 0), 1),    # unaligned + zero offset
+    (1, 32, 8, 8, 64, 32, 3, (64,), 1),     # MHA, page-aligned offset
+    (2, 8, 4, 1, 16, 16, 4, (5, 48), 1),    # MQA
+    (2, 16, 4, 2, 32, 8, 5, (19, 0), 3),    # each layer of a 3-layer pool
 ])
-def test_paged_prefill_sweep(b, c, hq, hkv, hd, page, pages, offs, dtype):
+def test_paged_prefill_sweep(b, c, hq, hkv, hd, page, pages, offs, layers,
+                             dtype):
     from repro.kernels.paged_prefill import paged_prefill_attention
     n = b * pages + 2
     q = _arr((b, c, hq, hd), dtype)
     kc = _arr((b, c, hkv, hd), dtype)
     vc = _arr((b, c, hkv, hd), dtype)
-    kp = _arr((n, page, hkv, hd), dtype)
-    vp = _arr((n, page, hkv, hd), dtype)
+    kp = _arr((layers, n, page, hkv, hd), dtype)
+    vp = _arr((layers, n, page, hkv, hd), dtype)
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     off = jnp.asarray(offs, jnp.int32)
-    out = paged_prefill_attention(q, kc, vc, kp, vp, bt, off,
-                                  interpret=True)
-    exp = ref.paged_prefill_attention_ref(q, kc, vc, kp, vp, bt, off)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32),
-                               rtol=tol, atol=tol)
+    for layer in range(layers):
+        out = paged_prefill_attention(q, kc, vc, kp, vp, bt, off, layer,
+                                      interpret=True)
+        exp = ref.paged_prefill_attention_ref(q, kc, vc, kp[layer],
+                                              vp[layer], bt, off)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(exp, np.float32),
+                                   rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("b,c,hq,dl,dr,page,pages,offs", [
@@ -187,11 +195,11 @@ def test_paged_prefill_ignores_pool_garbage_past_offset():
     q = _arr((b, c, hq, hd), jnp.float32)
     kc = _arr((b, c, hkv, hd), jnp.float32)
     vc = _arr((b, c, hkv, hd), jnp.float32)
-    kp = _arr((n, page, hkv, hd), jnp.float32)
-    vp = _arr((n, page, hkv, hd), jnp.float32)
+    kp = _arr((1, n, page, hkv, hd), jnp.float32)
+    vp = _arr((1, n, page, hkv, hd), jnp.float32)
     bt = jnp.arange(1, n, dtype=jnp.int32).reshape(1, pages)
     off = jnp.asarray([11], jnp.int32)    # mid-page offset
-    out = paged_prefill_attention(q, kc, vc, kp, vp, bt, off,
+    out = paged_prefill_attention(q, kc, vc, kp, vp, bt, off, 0,
                                   interpret=True)
     # poison everything at and past the offset
     mask = (jnp.arange(page)[None, :, None, None] +
@@ -199,7 +207,7 @@ def test_paged_prefill_ignores_pool_garbage_past_offset():
     out2 = paged_prefill_attention(q, kc, vc,
                                    jnp.where(mask, 999.0, kp),
                                    jnp.where(mask, 999.0, vp),
-                                   bt, off, interpret=True)
+                                   bt, off, 0, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2),
                                rtol=1e-5, atol=1e-5)
 
@@ -212,15 +220,15 @@ def test_paged_prefill_chunk_is_causal():
     q = _arr((b, c, hq, hd), jnp.float32)
     kc = _arr((b, c, hkv, hd), jnp.float32)
     vc = _arr((b, c, hkv, hd), jnp.float32)
-    kp = _arr((n, page, hkv, hd), jnp.float32)
-    vp = _arr((n, page, hkv, hd), jnp.float32)
+    kp = _arr((1, n, page, hkv, hd), jnp.float32)
+    vp = _arr((1, n, page, hkv, hd), jnp.float32)
     bt = jnp.arange(1, n, dtype=jnp.int32).reshape(1, pages)
     off = jnp.asarray([16], jnp.int32)
-    out = paged_prefill_attention(q, kc, vc, kp, vp, bt, off,
+    out = paged_prefill_attention(q, kc, vc, kp, vp, bt, off, 0,
                                   interpret=True)
     kc2 = kc.at[:, 5:].set(999.0)
     vc2 = vc.at[:, 5:].set(999.0)
-    out2 = paged_prefill_attention(q, kc2, vc2, kp, vp, bt, off,
+    out2 = paged_prefill_attention(q, kc2, vc2, kp, vp, bt, off, 0,
                                    interpret=True)
     np.testing.assert_allclose(np.asarray(out[:, :5]),
                                np.asarray(out2[:, :5]),
@@ -238,36 +246,41 @@ def _cpos(rows, c):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,c,hq,hkv,hd,page,pages,rows", [
+@pytest.mark.parametrize("b,c,hq,hkv,hd,page,pages,rows,layers", [
     # GQA: two runs straddling a resumed island + a ragged padded row
     (2, 8, 4, 2, 32, 8, 5, ([3, 4, 5, 6, 21, 22, 23, 24],
-                            [0, 1, 2, 3, 4, 5])),
+                            [0, 1, 2, 3, 4, 5]), 1),
     # MHA: page-aligned runs around a whole resumed page
     (1, 16, 8, 8, 64, 32, 3, ([8, 9, 10, 11, 12, 13, 14, 15,
-                               64, 65, 66, 67, 68, 69, 70, 71],)),
+                               64, 65, 66, 67, 68, 69, 70, 71],), 1),
     # MQA: run crossing a page boundary + a far gap
     (2, 8, 4, 1, 16, 16, 4, ([5, 6, 7, 8, 9, 50, 51, 52],
-                             [10, 11, 12, 13, 14, 15, 16, 17])),
+                             [10, 11, 12, 13, 14, 15, 16, 17]), 1),
+    # the GQA rows over each layer of a 3-layer pool
+    (2, 8, 4, 2, 32, 8, 5, ([3, 4, 5, 6, 21, 22, 23, 24],
+                            [0, 1, 2, 3, 4, 5]), 3),
 ])
 def test_paged_prefill_segments_sweep(b, c, hq, hkv, hd, page, pages,
-                                      rows, dtype):
+                                      rows, layers, dtype):
     from repro.kernels.paged_prefill import paged_prefill_segments
     n = b * pages + 2
     q = _arr((b, c, hq, hd), dtype)
     kc = _arr((b, c, hkv, hd), dtype)
     vc = _arr((b, c, hkv, hd), dtype)
-    kp = _arr((n, page, hkv, hd), dtype)
-    vp = _arr((n, page, hkv, hd), dtype)
+    kp = _arr((layers, n, page, hkv, hd), dtype)
+    vp = _arr((layers, n, page, hkv, hd), dtype)
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     cpos = _cpos(rows, c)
-    out = paged_prefill_segments(q, kc, vc, kp, vp, bt, cpos,
-                                 interpret=True)
-    exp = ref.paged_prefill_segments_ref(q, kc, vc, kp, vp, bt, cpos)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32),
-                               rtol=tol, atol=tol)
+    for layer in range(layers):
+        out = paged_prefill_segments(q, kc, vc, kp, vp, bt, cpos, layer,
+                                     interpret=True)
+        exp = ref.paged_prefill_segments_ref(q, kc, vc, kp[layer],
+                                             vp[layer], bt, cpos)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(exp, np.float32),
+                                   rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("b,c,hq,dl,dr,page,pages,rows", [
@@ -304,16 +317,16 @@ def test_paged_prefill_segments_degenerate_contiguous():
     q = _arr((b, c, hq, hd), jnp.float32)
     kc = _arr((b, c, hkv, hd), jnp.float32)
     vc = _arr((b, c, hkv, hd), jnp.float32)
-    kp = _arr((n, page, hkv, hd), jnp.float32)
-    vp = _arr((n, page, hkv, hd), jnp.float32)
+    kp = _arr((2, n, page, hkv, hd), jnp.float32)
+    vp = _arr((2, n, page, hkv, hd), jnp.float32)
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     offs = (19, 0)
     off = jnp.asarray(offs, jnp.int32)
     cpos = _cpos([[o + i for i in range(c)] for o in offs], c)
-    out_seg = paged_prefill_segments(q, kc, vc, kp, vp, bt, cpos,
+    out_seg = paged_prefill_segments(q, kc, vc, kp, vp, bt, cpos, 1,
                                      interpret=True)
-    out_off = paged_prefill_attention(q, kc, vc, kp, vp, bt, off,
+    out_off = paged_prefill_attention(q, kc, vc, kp, vp, bt, off, 1,
                                       interpret=True)
     np.testing.assert_array_equal(np.asarray(out_seg), np.asarray(out_off))
 
@@ -351,11 +364,11 @@ def test_paged_prefill_segments_ignore_unresident_slots():
     q = _arr((b, c, hq, hd), jnp.float32)
     kc = _arr((b, c, hkv, hd), jnp.float32)
     vc = _arr((b, c, hkv, hd), jnp.float32)
-    kp = _arr((n, page, hkv, hd), jnp.float32)
-    vp = _arr((n, page, hkv, hd), jnp.float32)
+    kp = _arr((1, n, page, hkv, hd), jnp.float32)
+    vp = _arr((1, n, page, hkv, hd), jnp.float32)
     bt = jnp.arange(1, n, dtype=jnp.int32).reshape(1, pages)
     cpos = _cpos([positions], c)
-    out = paged_prefill_segments(q, kc, vc, kp, vp, bt, cpos,
+    out = paged_prefill_segments(q, kc, vc, kp, vp, bt, cpos, 0,
                                  interpret=True)
     # poison every pool slot that is a chunk position or >= the last one
     pool_pos = (jnp.arange(page)[None, :, None, None] +
@@ -367,6 +380,6 @@ def test_paged_prefill_segments_ignore_unresident_slots():
     out2 = paged_prefill_segments(q, kc, vc,
                                   jnp.where(mask, 999.0, kp),
                                   jnp.where(mask, 999.0, vp),
-                                  bt, cpos, interpret=True)
+                                  bt, cpos, 0, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2),
                                rtol=1e-5, atol=1e-5)

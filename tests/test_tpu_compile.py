@@ -22,6 +22,7 @@ from repro.kernels.paged_prefill import (mla_paged_prefill_segments,
                                          paged_prefill_segments)
 
 BATCH, PAGE, PAGES, POOL, CHUNK = 8, 64, 32, 256, 64   # engine defaults
+LAYERS = 4             # the GQA kernels read one layer of a stacked pool
 GQA_ARCHS = ["llama3.2-1b", "glm4-9b"]
 
 
@@ -58,12 +59,86 @@ def _gqa(arch):
 @pytest.mark.parametrize("arch", GQA_ARCHS)
 def test_paged_decode_compiles(arch, one_chip):
     hq, hkv, hd = _gqa(arch)
-    _compile(lambda q, k, v, bt, ln: paged_decode_attention(
-        q, k, v, bt, ln, interpret=False), one_chip,
+    _compile(lambda q, k, v, bt, ln, layer: paged_decode_attention(
+        q, k, v, bt, ln, layer, interpret=False), one_chip,
         ((BATCH, hq, hd), jnp.bfloat16),
-        ((POOL, PAGE, hkv, hd), jnp.bfloat16),
-        ((POOL, PAGE, hkv, hd), jnp.bfloat16),
-        ((BATCH, PAGES), jnp.int32), ((BATCH,), jnp.int32))
+        ((LAYERS, POOL, PAGE, hkv, hd), jnp.bfloat16),
+        ((LAYERS, POOL, PAGE, hkv, hd), jnp.bfloat16),
+        ((BATCH, PAGES), jnp.int32), ((BATCH,), jnp.int32),
+        ((), jnp.int32))
+
+
+def _pool_program(which, sharding, monkeypatch):
+    """The compiled fused decode step or prefill chunk of a 2-layer model
+    at glm4-9b's widths over a pool of 2,049 pages (16 slots of 8,192
+    tokens), with the Pallas kernels; and the pool's shape."""
+    import dataclasses
+    import functools
+
+    from repro.kernels import backend as kb
+    from repro.models.model import build_model
+    from repro.serving import engine as eng_mod
+
+    monkeypatch.setattr(kb, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("glm4-9b"), n_layers=2)
+    model = build_model(cfg)
+    slots, pages = 16, 128
+    pool = (cfg.n_layers, 1 + slots * pages, PAGE, cfg.n_kv_heads, cfg.hd)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          model.abstract_params())
+    pools = {k: sds(pool, jnp.bfloat16) for k in ("k_pages", "v_pages")}
+    if which == "fused_decode":
+        fn = jax.jit(functools.partial(eng_mod._fused_paged_step, model,
+                                       "pallas"), donate_argnums=(1,))
+        rows = sds((slots,), jnp.int32)
+        low = fn.lower(params, dict(pools, block_tables=sds(
+            (slots, pages), jnp.int32), lengths=rows), rows, rows,
+            sds((2,), jnp.uint32), sds((slots,), jnp.float32), rows,
+            sds((slots,), jnp.float32))
+    else:
+        fn = jax.jit(functools.partial(model.prefill_chunk,
+                                       backend="pallas"))
+        low = fn.lower(params, dict(pools, block_table=sds(
+            (1, pages), jnp.int32)), sds((1, CHUNK), jnp.int32),
+            sds((1,), jnp.int32))
+    return low.compile(), pool
+
+
+@pytest.mark.parametrize("which", ["fused_decode", "prefill_chunk"])
+def test_gqa_programs_read_the_pool_in_place(which, one_chip, monkeypatch):
+    """The GQA decode step and prefill chunk hand the kernels the whole
+    [L, N, page, Hkv, hd] pool and a layer index: no layer is sliced out
+    of the stack (a 67 MB copy per layer here) or stacked back.  So the
+    program's temporaries stay below one layer's K slice, and nothing
+    but the in-place scatter of the new tokens (a fusion around a
+    scatter) writes an array of the pool's shape or a layer's."""
+    import math
+    import re
+    compiled, pool = _pool_program(which, one_chip, monkeypatch)
+    layer_bytes = math.prod(pool[1:]) * jnp.dtype(jnp.bfloat16).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    text = compiled.as_text()
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"^%(\S+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+    shapes = {",".join(map(str, pool)), ",".join(map(str, pool[1:]))}
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if not m or m.group(1) not in shapes or m.group(2) in (
+                "parameter", "get-tuple-element", "bitcast", "scatter",
+                "custom-call"):
+            continue
+        called = re.search(r"calls=%([\w.-]+)", line)
+        if m.group(2) == "fusion" and called and \
+                " scatter(" in bodies.get(called.group(1), ""):
+            continue
+        moved.append(line[:160])
+    assert not moved, moved
 
 
 @pytest.mark.parametrize("arch", GQA_ARCHS)
@@ -86,14 +161,15 @@ def test_paged_decode_int8_compiles(arch, one_chip):
 ], ids=["offsets", "segments"])
 def test_paged_prefill_compiles(arch, kernel, positions, one_chip):
     hq, hkv, hd = _gqa(arch)
-    _compile(lambda q, kc, vc, kp, vp, bt, pos: kernel(
-        q, kc, vc, kp, vp, bt, pos, interpret=False), one_chip,
+    _compile(lambda q, kc, vc, kp, vp, bt, pos, layer: kernel(
+        q, kc, vc, kp, vp, bt, pos, layer, interpret=False), one_chip,
         ((BATCH, CHUNK, hq, hd), jnp.bfloat16),
         ((BATCH, CHUNK, hkv, hd), jnp.bfloat16),
         ((BATCH, CHUNK, hkv, hd), jnp.bfloat16),
-        ((POOL, PAGE, hkv, hd), jnp.bfloat16),
-        ((POOL, PAGE, hkv, hd), jnp.bfloat16),
-        ((BATCH, PAGES), jnp.int32), (positions, jnp.int32))
+        ((LAYERS, POOL, PAGE, hkv, hd), jnp.bfloat16),
+        ((LAYERS, POOL, PAGE, hkv, hd), jnp.bfloat16),
+        ((BATCH, PAGES), jnp.int32), (positions, jnp.int32),
+        ((), jnp.int32))
 
 
 def test_mla_decode_compiles(one_chip):

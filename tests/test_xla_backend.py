@@ -87,25 +87,28 @@ def test_engine_validates_backend():
 # ---------------------------------------------------------------------------
 # decode: xla vs oracle vs interpret across head layouts + ragged lengths
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("b,hq,hkv,hd,page,pages", [
-    (2, 8, 2, 64, 64, 4),       # GQA
-    (3, 4, 4, 32, 32, 3),       # MHA
-    (1, 8, 1, 128, 64, 2),      # MQA
+@pytest.mark.parametrize("b,hq,hkv,hd,page,pages,layers", [
+    (2, 8, 2, 64, 64, 4, 1),    # GQA
+    (3, 4, 4, 32, 32, 3, 1),    # MHA
+    (1, 8, 1, 128, 64, 2, 1),   # MQA
+    (2, 8, 2, 64, 64, 4, 3),    # GQA, each layer of a 3-layer pool
 ])
-def test_paged_decode_xla_equivalence(b, hq, hkv, hd, page, pages):
+def test_paged_decode_xla_equivalence(b, hq, hkv, hd, page, pages, layers):
     n = b * pages + 2
     q = _arr((b, hq, hd))
-    kp, vp = _arr((n, page, hkv, hd)), _arr((n, page, hkv, hd))
+    kp = _arr((layers, n, page, hkv, hd))
+    vp = _arr((layers, n, page, hkv, hd))
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     ln = jnp.asarray(RNG.integers(1, pages * page, size=b), jnp.int32)
-    out = ops.paged_decode(q, kp, vp, bt, ln, backend="xla")
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref.paged_decode_attention_ref(
-            q, kp, vp, bt, ln)), **TOL)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ops.paged_decode(
-            q, kp, vp, bt, ln, backend="interpret")), **TOL)
+    for layer in range(layers):
+        out = ops.paged_decode(q, kp, vp, bt, ln, layer, backend="xla")
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref.paged_decode_attention_ref(
+                q, kp[layer], vp[layer], bt, ln)), **TOL)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ops.paged_decode(
+                q, kp, vp, bt, ln, layer, backend="interpret")), **TOL)
 
 
 @pytest.mark.parametrize("b,hq,dl,dr,page,pages", [
@@ -137,20 +140,21 @@ def test_paged_decode_xla_ignores_padded_table_entries():
     b, hq, hkv, hd, page, pages = 2, 4, 2, 32, 16, 4
     n = b * pages + 1
     q = _arr((b, hq, hd))
-    kp, vp = _arr((n, page, hkv, hd)), _arr((n, page, hkv, hd))
+    kp = _arr((1, n, page, hkv, hd))
+    vp = _arr((1, n, page, hkv, hd))
     ln = jnp.asarray([17, 5], jnp.int32)     # 2 pages / 1 page valid
     bt = jnp.asarray([[1, 2, 0, 0], [3, 0, 0, 0]], jnp.int32)
-    out = ops.paged_decode(q, kp, vp, bt, ln, backend="xla")
+    out = ops.paged_decode(q, kp, vp, bt, ln, 0, backend="xla")
     # redirect the padded entries to poisoned pages: output unchanged
-    kp2 = kp.at[4:].set(999.0)
-    vp2 = vp.at[4:].set(999.0)
+    kp2 = kp.at[:, 4:].set(999.0)
+    vp2 = vp.at[:, 4:].set(999.0)
     bt2 = jnp.asarray([[1, 2, 4, 5], [3, 6, 7, 8]], jnp.int32)
-    out2 = ops.paged_decode(q, kp2, vp2, bt2, ln, backend="xla")
+    out2 = ops.paged_decode(q, kp2, vp2, bt2, ln, 0, backend="xla")
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2), **TOL)
     # an out-of-range padding id clamps into the pool (mode="clip" —
     # the same semantics as the oracles' fancy indexing), never NaN-fills
     bt3 = jnp.asarray([[1, 2, 4, 99], [3, 6, 7, 99]], jnp.int32)
-    out3 = ops.paged_decode(q, kp2, vp2, bt3, ln, backend="xla")
+    out3 = ops.paged_decode(q, kp2, vp2, bt3, ln, 0, backend="xla")
     assert not bool(jnp.any(jnp.isnan(out3)))
     np.testing.assert_allclose(np.asarray(out), np.asarray(out3), **TOL)
 
@@ -182,26 +186,32 @@ def test_flash_causal_xla_matches_oracle():
 # ---------------------------------------------------------------------------
 # prefill: xla vs oracle vs interpret, incl. masked mid-prefill slots
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("b,c,hq,hkv,hd,page,pages,offs", [
-    (2, 16, 4, 2, 32, 8, 5, (19, 0)),     # GQA, unaligned + zero offset
-    (1, 32, 8, 8, 64, 32, 3, (64,)),      # MHA, page-aligned offset
-    (2, 8, 4, 1, 16, 16, 4, (5, 48)),     # MQA
+@pytest.mark.parametrize("b,c,hq,hkv,hd,page,pages,offs,layers", [
+    (2, 16, 4, 2, 32, 8, 5, (19, 0), 1),  # GQA, unaligned + zero offset
+    (1, 32, 8, 8, 64, 32, 3, (64,), 1),   # MHA, page-aligned offset
+    (2, 8, 4, 1, 16, 16, 4, (5, 48), 1),  # MQA
+    (2, 16, 4, 2, 32, 8, 5, (19, 0), 3),  # GQA, each of 3 layers
 ])
-def test_paged_prefill_xla_equivalence(b, c, hq, hkv, hd, page, pages, offs):
+def test_paged_prefill_xla_equivalence(b, c, hq, hkv, hd, page, pages, offs,
+                                       layers):
     n = b * pages + 2
     q = _arr((b, c, hq, hd))
     kc, vc = _arr((b, c, hkv, hd)), _arr((b, c, hkv, hd))
-    kp, vp = _arr((n, page, hkv, hd)), _arr((n, page, hkv, hd))
+    kp = _arr((layers, n, page, hkv, hd))
+    vp = _arr((layers, n, page, hkv, hd))
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     off = jnp.asarray(offs, jnp.int32)
-    out = ops.paged_prefill(q, kc, vc, kp, vp, bt, off, backend="xla")
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref.paged_prefill_attention_ref(
-            q, kc, vc, kp, vp, bt, off)), **TOL)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ops.paged_prefill(
-            q, kc, vc, kp, vp, bt, off, backend="interpret")), **TOL)
+    for layer in range(layers):
+        out = ops.paged_prefill(q, kc, vc, kp, vp, bt, off, layer,
+                                backend="xla")
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref.paged_prefill_attention_ref(
+                q, kc, vc, kp[layer], vp[layer], bt, off)), **TOL)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ops.paged_prefill(
+                q, kc, vc, kp, vp, bt, off, layer, backend="interpret")),
+            **TOL)
 
 
 @pytest.mark.parametrize("b,c,hq,dl,dr,page,pages,offs", [
@@ -235,16 +245,17 @@ def test_prefill_xla_ignores_pool_garbage_past_offset():
     n = pages + 1
     q = _arr((b, c, hq, hd))
     kc, vc = _arr((b, c, hkv, hd)), _arr((b, c, hkv, hd))
-    kp, vp = _arr((n, page, hkv, hd)), _arr((n, page, hkv, hd))
+    kp = _arr((1, n, page, hkv, hd))
+    vp = _arr((1, n, page, hkv, hd))
     bt = jnp.arange(1, n, dtype=jnp.int32).reshape(1, pages)
     off = jnp.asarray([11], jnp.int32)       # mid-page offset
-    out = ops.paged_prefill(q, kc, vc, kp, vp, bt, off, backend="xla")
+    out = ops.paged_prefill(q, kc, vc, kp, vp, bt, off, 0, backend="xla")
     mask = (jnp.arange(page)[None, :, None, None] +
             page * jnp.arange(n)[:, None, None, None] - page) >= 11
     out2 = ops.paged_prefill(q, kc, vc,
                              jnp.where(mask, 999.0, kp),
                              jnp.where(mask, 999.0, vp),
-                             bt, off, backend="xla")
+                             bt, off, 0, backend="xla")
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2), **TOL)
 
 
@@ -253,12 +264,13 @@ def test_prefill_xla_chunk_is_causal():
     n = pages + 1
     q = _arr((b, c, hq, hd))
     kc, vc = _arr((b, c, hkv, hd)), _arr((b, c, hkv, hd))
-    kp, vp = _arr((n, page, hkv, hd)), _arr((n, page, hkv, hd))
+    kp = _arr((1, n, page, hkv, hd))
+    vp = _arr((1, n, page, hkv, hd))
     bt = jnp.arange(1, n, dtype=jnp.int32).reshape(1, pages)
     off = jnp.asarray([16], jnp.int32)
-    out = ops.paged_prefill(q, kc, vc, kp, vp, bt, off, backend="xla")
+    out = ops.paged_prefill(q, kc, vc, kp, vp, bt, off, 0, backend="xla")
     out2 = ops.paged_prefill(q, kc.at[:, 5:].set(999.0),
-                             vc.at[:, 5:].set(999.0), kp, vp, bt, off,
+                             vc.at[:, 5:].set(999.0), kp, vp, bt, off, 0,
                              backend="xla")
     np.testing.assert_allclose(np.asarray(out[:, :5]),
                                np.asarray(out2[:, :5]), **TOL)
@@ -272,34 +284,40 @@ def _cpos(rows, c):
                        jnp.int32)
 
 
-@pytest.mark.parametrize("b,c,hq,hkv,hd,page,pages,rows", [
+@pytest.mark.parametrize("b,c,hq,hkv,hd,page,pages,rows,layers", [
     # GQA: two runs around a resumed island + a ragged padded row
     (2, 8, 4, 2, 32, 8, 5, ([3, 4, 5, 6, 21, 22, 23, 24],
-                            [0, 1, 2, 3, 4, 5])),
+                            [0, 1, 2, 3, 4, 5]), 1),
     # MHA: page-aligned runs around a whole resumed page
     (1, 16, 8, 8, 64, 32, 3, ([8, 9, 10, 11, 12, 13, 14, 15,
-                               64, 65, 66, 67, 68, 69, 70, 71],)),
+                               64, 65, 66, 67, 68, 69, 70, 71],), 1),
     # MQA: run crossing a page boundary + a far gap
     (2, 8, 4, 1, 16, 16, 4, ([5, 6, 7, 8, 9, 50, 51, 52],
-                             [10, 11, 12, 13, 14, 15, 16, 17])),
+                             [10, 11, 12, 13, 14, 15, 16, 17]), 1),
+    # the GQA rows over each layer of a 3-layer pool
+    (2, 8, 4, 2, 32, 8, 5, ([3, 4, 5, 6, 21, 22, 23, 24],
+                            [0, 1, 2, 3, 4, 5]), 3),
 ])
 def test_paged_prefill_seg_xla_equivalence(b, c, hq, hkv, hd, page,
-                                           pages, rows):
+                                           pages, rows, layers):
     n = b * pages + 2
     q = _arr((b, c, hq, hd))
     kc, vc = _arr((b, c, hkv, hd)), _arr((b, c, hkv, hd))
-    kp, vp = _arr((n, page, hkv, hd)), _arr((n, page, hkv, hd))
+    kp = _arr((layers, n, page, hkv, hd))
+    vp = _arr((layers, n, page, hkv, hd))
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     cpos = _cpos(rows, c)
-    out = ops.paged_prefill_seg(q, kc, vc, kp, vp, bt, cpos,
-                                backend="xla")
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref.paged_prefill_segments_ref(
-            q, kc, vc, kp, vp, bt, cpos)), **TOL)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ops.paged_prefill_seg(
-            q, kc, vc, kp, vp, bt, cpos, backend="interpret")), **TOL)
+    for layer in range(layers):
+        out = ops.paged_prefill_seg(q, kc, vc, kp, vp, bt, cpos, layer,
+                                    backend="xla")
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref.paged_prefill_segments_ref(
+                q, kc, vc, kp[layer], vp[layer], bt, cpos)), **TOL)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ops.paged_prefill_seg(
+                q, kc, vc, kp, vp, bt, cpos, layer, backend="interpret")),
+            **TOL)
 
 
 @pytest.mark.parametrize("b,c,hq,dl,dr,page,pages,rows", [
@@ -334,15 +352,16 @@ def test_paged_prefill_seg_xla_degenerate_contiguous():
     n = b * pages + 2
     q = _arr((b, c, hq, hd))
     kc, vc = _arr((b, c, hkv, hd)), _arr((b, c, hkv, hd))
-    kp, vp = _arr((n, page, hkv, hd)), _arr((n, page, hkv, hd))
+    kp = _arr((2, n, page, hkv, hd))
+    vp = _arr((2, n, page, hkv, hd))
     bt = jnp.asarray(RNG.permutation(n)[:b * pages].reshape(b, pages),
                      jnp.int32)
     offs = (19, 0)
     cpos = _cpos([[o + i for i in range(c)] for o in offs], c)
-    out_seg = ops.paged_prefill_seg(q, kc, vc, kp, vp, bt, cpos,
+    out_seg = ops.paged_prefill_seg(q, kc, vc, kp, vp, bt, cpos, 1,
                                     backend="xla")
     out_off = ops.paged_prefill(q, kc, vc, kp, vp, bt,
-                                jnp.asarray(offs, jnp.int32),
+                                jnp.asarray(offs, jnp.int32), 1,
                                 backend="xla")
     np.testing.assert_allclose(np.asarray(out_seg), np.asarray(out_off),
                                **TOL)
